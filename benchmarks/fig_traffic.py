@@ -51,6 +51,11 @@ WEIGHTS = [2.0, 1.0]            # latency class weighted, affinity-preserving
 POLICIES = ("grin-p", "cab-p", "lb", "jsq")
 
 
+UTILS, SEEDS = (0.5, 0.7, 0.85, 0.95, 1.05, 1.2), (0, 1, 2)
+# offered rate at which the busiest class fills its best pool
+X_KNEE = 1.0 / max(SHARES[c] / MU[c].max() for c in range(len(SHARES)))
+
+
 def _target_for(pname, mix):
     if pname in ("lb", "jsq"):
         return _BASELINE_MODES[pname], np.zeros(MU.shape, np.int64)
@@ -58,52 +63,69 @@ def _target_for(pname, mix):
     return MODE_DEFICIT, np.asarray(pol.solve_target(MU, mix))
 
 
+def sample_arrivals(utils, seeds, n_arrivals):
+    """Per-load Poisson TrafficSpecs and the shared arrival realization
+    (times, types) of every (util, seed) point, in utils x seeds order."""
+    specs = {u: TrafficSpec(tuple(PoissonArrivals(u * X_KNEE * s)
+                                  for s in SHARES), np.eye(len(SHARES)))
+             for u in utils}
+    arr = {(u, s): specs[u].sample(s, n_arrivals)
+           for u in utils for s in seeds}
+    return specs, arr
+
+
+def simulate(pname, admit, arr, mix, warmup_arrivals, hist=None):
+    """Every (util, seed) point of `arr` under one policy and admission
+    cap, in ONE `simulate_open_batch` device call."""
+    points = list(arr)
+    B = len(points)
+    mode, target = _target_for(pname, mix)
+    return simulate_open_batch(
+        np.broadcast_to(MU, (B,) + MU.shape),
+        np.broadcast_to(target, (B,) + target.shape),
+        np.stack([arr[p][0] for p in points]),
+        np.stack([arr[p][1] for p in points]),
+        [p[1] for p in points], distribution=make_distribution("exponential"),
+        queue_capacity=QCAP, order="PS", warmup_arrivals=warmup_arrivals,
+        class_of_type=CLS, modes=np.full(B, mode, np.int32),
+        admit_limits=np.broadcast_to(np.asarray(admit, np.int64),
+                                     (B, len(SHARES))),
+        hist=hist or LogHistogram(), deadlines=DEADLINES)
+
+
+def host_oracle(spec, mix, n_arrivals, warmup_arrivals, seed):
+    """GrIn-P on the host event loop at one load: the oracle the device
+    sweep is held to (same arrival realization, own size stream)."""
+    cfg = open_sim_config(
+        MU, spec, n_arrivals=n_arrivals, warmup_arrivals=warmup_arrivals,
+        queue_capacity=QCAP, deadlines=DEADLINES, class_of_type=CLS,
+        target_mix=mix, distribution=make_distribution("exponential"),
+        order="PS", seed=seed)
+    return ClosedNetworkSimulator(cfg).run(
+        get_policy("grin-p", weights=WEIGHTS))
+
+
 def run(n_arrivals: int = 20000, warmup_arrivals: int = 2000,
-        utils=(0.5, 0.7, 0.85, 0.95, 1.05, 1.2), seeds=(0, 1, 2),
-        smoke: bool = False):
+        utils=UTILS, seeds=SEEDS, smoke: bool = False):
     if smoke:
         n_arrivals, warmup_arrivals = 2500, 250
         utils, seeds = (0.5, 0.95, 1.2), (0,)
-    x_knee = 1.0 / max(SHARES[c] / MU[c].max() for c in range(len(SHARES)))
-    dist = make_distribution("exponential")
     hist = LogHistogram()
     u_hi = max(utils)
     payload = {"smoke": smoke, "n_arrivals": n_arrivals,
                "warmup_arrivals": warmup_arrivals, "utils": list(utils),
                "seeds": list(seeds), "mu": MU.tolist(),
-               "shares": SHARES.tolist(), "x_knee": float(x_knee),
+               "shares": SHARES.tolist(), "x_knee": float(X_KNEE),
                "queue_capacity": QCAP, "deadlines": DEADLINES.tolist(),
                "hist_rel_error_bound": float(hist.rel_error_bound)}
 
     # shared arrival realizations + per-class offered counts in-window
-    arr, offered_c = {}, {}
-    specs = {}
-    for u in utils:
-        specs[u] = TrafficSpec(
-            tuple(PoissonArrivals(u * x_knee * s) for s in SHARES),
-            np.eye(len(SHARES)))
-        for s in seeds:
-            times, tys = specs[u].sample(s, n_arrivals)
-            arr[(u, s)] = (times, tys)
-            offered_c[(u, s)] = np.bincount(tys[warmup_arrivals:],
-                                            minlength=len(SHARES))
+    specs, arr = sample_arrivals(utils, seeds, n_arrivals)
+    offered_c = {p: np.bincount(tys[warmup_arrivals:], minlength=len(SHARES))
+                 for p, (_, tys) in arr.items()}
     mix = derive_target_mix(specs[u_hi], MU.shape[1], QCAP)
-    points = [(u, s) for u in utils for s in seeds]
+    points = list(arr)
     B = len(points)
-
-    def batch(pname, admit):
-        mode, target = _target_for(pname, mix)
-        return simulate_open_batch(
-            np.broadcast_to(MU, (B,) + MU.shape),
-            np.broadcast_to(target, (B,) + target.shape),
-            np.stack([arr[p][0] for p in points]),
-            np.stack([arr[p][1] for p in points]),
-            [p[1] for p in points], distribution=dist, queue_capacity=QCAP,
-            order="PS", warmup_arrivals=warmup_arrivals, class_of_type=CLS,
-            modes=np.full(B, mode, np.int32),
-            admit_limits=np.broadcast_to(np.asarray(admit, np.int64),
-                                         (B, len(SHARES))),
-            hist=hist, deadlines=DEADLINES)
 
     variants = [(p, [N_SLOTS, N_SLOTS]) for p in POLICIES]
     variants += [("jsq+adm", [N_SLOTS, QCAP // 2]),
@@ -112,7 +134,7 @@ def run(n_arrivals: int = 20000, warmup_arrivals: int = 2000,
     for disp, admit in variants:
         pname = disp.split("+")[0]
         with Timer() as t:
-            out = batch(pname, admit)
+            out = simulate(pname, admit, arr, mix, warmup_arrivals, hist)
         emit(f"fig_traffic_{disp}", t.us / B, f"points={B};wall={t.dt:.2f}s")
         results[disp] = out
         rows = {}
@@ -176,14 +198,9 @@ def run(n_arrivals: int = 20000, warmup_arrivals: int = 2000,
     # 4. host oracle vs device engine at one matched point (same arrival
     # realization; size streams differ, so tolerances are statistical)
     u_ref = 0.95 if smoke else 0.85
-    cfg = open_sim_config(
-        MU, specs[u_ref], n_arrivals=n_arrivals,
-        warmup_arrivals=warmup_arrivals, queue_capacity=QCAP,
-        deadlines=DEADLINES, class_of_type=CLS, target_mix=mix,
-        distribution=dist, order="PS", seed=seeds[0])
     with Timer() as t:
-        host = ClosedNetworkSimulator(cfg).run(
-            get_policy("grin-p", weights=WEIGHTS))
+        host = host_oracle(specs[u_ref], mix, n_arrivals, warmup_arrivals,
+                           seeds[0])
     emit("fig_traffic_host_oracle", t.us, f"wall={t.dt:.2f}s")
     i_ref = points.index((u_ref, seeds[0]))
     dev = results["grin-p"]

@@ -329,7 +329,7 @@ def grin_x_sys_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
 # Batched block-move GrIn: the device production path. One lax.while_loop
 # advances a whole (mu, mix) batch; each iteration scores EVERY (block size,
 # type, src, dst) move for every instance in one vectorized pass (Pallas
-# kernel on TPU, jnp reference elsewhere — bit-identical) and applies the
+# kernel on TPU, jnp reference elsewhere) and applies the
 # selected block (steepest-single-move direction, best ladder size along it)
 # per instance. Converged instances carry a per-instance mask so they stop
 # mutating (and stop counting moves) while the rest of the batch drains; the
@@ -337,9 +337,8 @@ def grin_x_sys_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("n_sizes", "max_moves",
-                                             "use_kernel", "objective"))
-def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, use_kernel,
-                     objective):
+                                             "kernel", "objective"))
+def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, kernel, objective):
     from repro.core.energy import edp_batch_jax, expected_energy_batch_jax
     from repro.kernels.grin_moves import (OBJ_E_GUARD, OBJ_EDP, OBJ_X,
                                           OBJ_XE, block_move_scores)
@@ -362,10 +361,9 @@ def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, use_kernel,
     def run_phase(N0_, moves0, obj):
         def body(state):
             N, active, moves, it = state
-            _, bi, bg, base = block_move_scores(N, mus, sizes,
-                                                use_kernel=use_kernel,
-                                                return_gains=False,
-                                                P=Ps, objective=obj)
+            _, bi, bg, base = block_move_scores(
+                N, mus, sizes, use_kernel=kernel != "jnp-reference",
+                return_gains=False, P=Ps, objective=obj)
             mi, p, s, d = jnp.unravel_index(bi, (n_sizes, k, l, l))
             m = sizes[mi]                                    # (B,)
             # Convergence is the m=1 signal: exhausted => single-move
@@ -421,7 +419,8 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
     `max_moves=None` caps the loop at the batch's max population + 64 — block
     convergence needs O(log N)-ish moves, so hitting the cap (converged
     False) signals a degenerate instance rather than a small budget.
-    `use_kernel` picks the Pallas scoring kernel (None: TPU/interpret auto).
+    `use_kernel` picks the Pallas scoring kernel (None: compiled on TPU,
+    jnp elsewhere; see `repro.kernels.grin_moves.kernel_mode`).
 
     `objective` selects what moves are ranked by (paper Sec. 3.4 /
     arXiv:1607.07763 multi-objective framing), with the power matrix
@@ -461,10 +460,9 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
         Ps = power_matrix_jax(mus, power or PROPORTIONAL_POWER)
     if n_sizes is None:
         n_sizes = len(_ladder(int(np.asarray(n_tasks_batch).sum(axis=1).max())))
-    if use_kernel is None:
-        from repro.kernels.grin_moves import _interpret, _use_pallas
-        use_kernel = _use_pallas() or _interpret()
+    from repro.kernels.grin_moves import kernel_mode
     from repro.obs.profile import span as _obs_span
     with _obs_span("grin_solve_batch_jax") as sp:
         return sp.ready(_grin_block_core(mus, mixes, Ps, int(n_sizes),
-                                         max_moves, bool(use_kernel), obj))
+                                         max_moves, kernel_mode(use_kernel),
+                                         obj))

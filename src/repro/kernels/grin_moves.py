@@ -22,14 +22,20 @@ first, so ties prefer the biggest block). The m=1 best gain doubles as the
 convergence signal: when it is exhausted the state is a single-move local
 maximum, exactly the fixed-point class Lemma 8 terminates in.
 
+Layout: the shared bodies work BATCH-LAST — states are (k, l, B), gains
+(M, k, l_s, l_d, B) — so the batch sits on the TPU's 128-wide lane axis and
+the (l_d, B) trailing tile is lane-dense. Everything is built from ops the
+Mosaic compiler lowers: elementwise math, broadcasts, static slices,
+leading-axis stacks, max/min reductions, and iota compares. There is no
+gather, reversal or cumulative scan: argmax is "first index of the max"
+against an iota, and the size-ladder prefix test is a static unrolled loop.
+
 Three entry points:
 
-  * `block_move_gains_ref`  — pure-jnp gain scoring (also the CPU production
-    path inside the jitted solver loop).
-  * `block_move_gains_pallas` — Pallas kernel tiled over the batch dimension
-    (grid over B-tiles; each step scores one (Bt, k, l) slab in VMEM and
-    runs the selection in-kernel). The kernel body is op-for-op the
-    reference, so outputs are bit-identical.
+  * `block_move_gains_ref`  — pure-jnp gain scoring.
+  * `block_move_gains_pallas` — Pallas kernel tiled over the batch (lane)
+    axis: each grid step scores one (k, l, Bt) slab in VMEM and runs the
+    selection in-kernel, with the same body functions as the reference.
   * `block_move_scores` — dispatching wrapper returning
     (gains (B, F), best_idx (B,), best_gain (B,), base_gain (B,)) with
     F = M*k*l*l, best_idx/best_gain the selected move, and base_gain the
@@ -45,15 +51,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams to CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 _NEG = -jnp.inf
 
 # Objectives the scorer can rank moves under (trace-time statics). OBJ_X is
-# the original throughput objective (bit-compatible path); the energy
-# objectives additionally take the power matrix P:
+# the original throughput objective; the energy objectives additionally
+# take the power matrix P:
 #   OBJ_XE      — gains are still dX, but near-tied directions (within
 #                 _XE_TIE float32 resolution) break toward the larger energy
 #                 drop: "max-X subject to energy" move selection.
@@ -64,6 +66,7 @@ _NEG = -jnp.inf
 #                 follows an OBJ_XE solve (grin-e phase 2).
 OBJ_X, OBJ_XE, OBJ_E, OBJ_EDP, OBJ_E_GUARD = 0, 1, 2, 3, 4
 _XE_TIE = 4e-6          # float32 near-tie band, matches grin._TOL32
+_LANES = 128            # batch tile: one vreg row of lanes
 
 
 def _use_pallas() -> bool:
@@ -74,29 +77,62 @@ def _interpret() -> bool:
     return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
 
 
+def kernel_mode(use_kernel: bool | None = None) -> str:
+    """Which scoring path `block_move_scores(use_kernel=...)` takes:
+    "jnp-reference" (use_kernel=False, or None off-TPU),
+    "pallas-interpret" (only when REPRO_PALLAS_INTERPRET=1 asks for it), or
+    "pallas-compiled" (use_kernel=True, or None on a TPU)."""
+    if use_kernel is False:
+        return "jnp-reference"
+    if _interpret():
+        return "pallas-interpret"
+    if use_kernel or _use_pallas():
+        return "pallas-compiled"
+    return "jnp-reference"
+
+
+def _src_to_pairs(x):
+    """(..., l, B) -> (..., l_s, l_d, B) with x[..., s, :] at every d: a
+    stack of static sublane-slice broadcasts (no reshape across tiles)."""
+    return jnp.stack([jnp.broadcast_to(x[..., s:s + 1, :], x.shape)
+                      for s in range(x.shape[-2])], axis=-3)
+
+
+def _eye(l, b):
+    """(l_s, l_d, B) bool mask of the s == d diagonal."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (l, b), 0)
+    return _src_to_pairs(iota) == iota
+
+
+def _col_rate(N, R):
+    """Per-column average rate sum_p R N / c_j, 0 on empty columns: (l, B)."""
+    colsum = N.sum(axis=0)
+    return jnp.where(colsum > 0, (R * N).sum(axis=0)
+                     / jnp.maximum(colsum, 1.0), 0.0)
+
+
+def _add_rem(m, Mb, Sb, colsum):
+    """Block add/remove closed forms for a (k, l, B) rate tile Mb over the
+    (l, B) column averages Sb: ((M, k, l, B), (M, k, l, B))."""
+    add = m * (Mb - Sb) / (colsum + m)
+    rem = jnp.where(colsum - m > 0.5,
+                    m * (Sb - Mb) / jnp.maximum(colsum - m, 1.0), -Sb)
+    return add, rem
+
+
 def _gains_body(N, mu, sizes):
-    """Shared math: N, mu (B, k, l) float32; sizes (M,) float32 -> gain
-    (B, M, k, l, l). MUST stay op-identical between the reference and the
-    kernel body — bit-exact parity is an acceptance criterion."""
-    l = N.shape[-1]
-    colsum = N.sum(axis=-2)                              # (B, l)
-    w = (mu * N).sum(axis=-2)                            # (B, l)
-    X = jnp.where(colsum > 0, w / jnp.maximum(colsum, 1.0), 0.0)
-    m = sizes[None, :, None, None]                       # (1, M, 1, 1)
-    cb = colsum[:, None, None, :]                        # (B, 1, 1, l)
-    Xb = X[:, None, None, :]
-    mub = mu[:, None, :, :]                              # (B, 1, k, l)
-    add = m * (mub - Xb) / (cb + m)                      # (B, M, k, l)
-    rem = jnp.where(cb - m > 0.5,
-                    m * (Xb - mub) / jnp.maximum(cb - m, 1.0), -Xb)
-    rem = jnp.where(N[:, None, :, :] >= m, rem, _NEG)    # infeasible removes
-    gain = rem[..., :, None] + add[..., None, :]         # (B, M, k, l, l)
-    eye = jnp.eye(l, dtype=bool)[None, None, None]
-    return jnp.where(eye, _NEG, gain)
+    """Shared math: N, mu (k, l, B) float32; sizes (M, 1, 1, 1) float32 ->
+    gain (M, k, l_s, l_d, B). MUST stay op-identical between the reference
+    and the kernel body."""
+    colsum = N.sum(axis=0)                               # (l, B)
+    add, rem = _add_rem(sizes, mu, _col_rate(N, mu), colsum)
+    rem = jnp.where(N >= sizes, rem, _NEG)               # infeasible removes
+    gain = _src_to_pairs(rem) + add[:, :, None]
+    return jnp.where(_eye(*colsum.shape), _NEG, gain)
 
 
 def _energy_gains_body(N, mu, P, sizes, objective):
-    """Energy-aware gain scoring: (gain (B, M, k, l, l), tie | None).
+    """Energy-aware gain scoring: (gain (M, k, l, l, B), tie | None).
 
     The per-column power rate W_j = sum_i N_ij P_ij / c_j has the same
     ratio-of-sums shape as X_j, so the block closed forms apply with P in
@@ -108,31 +144,18 @@ def _energy_gains_body(N, mu, P, sizes, objective):
     and gains are the NEGATED deltas (drops — bigger is better). Infeasible
     moves (src short of m tasks, s == d, or a move that drains the system)
     score -inf. MUST stay op-identical between the jnp reference and the
-    Pallas kernel body — bit-exact parity is an acceptance criterion."""
-    l = N.shape[-1]
-    colsum = N.sum(axis=-2)                              # (B, l)
-    wx = (mu * N).sum(axis=-2)
-    wp = (P * N).sum(axis=-2)
-    X = jnp.where(colsum > 0, wx / jnp.maximum(colsum, 1.0), 0.0)
-    W = jnp.where(colsum > 0, wp / jnp.maximum(colsum, 1.0), 0.0)
-    Xs = X.sum(-1)[:, None, None, None, None]            # (B, 1, 1, 1, 1)
-    Ws = W.sum(-1)[:, None, None, None, None]
-    ntot = colsum.sum(-1)[:, None, None, None, None]
-    m = sizes[None, :, None, None]                       # (1, M, 1, 1)
-    cb = colsum[:, None, None, :]                        # (B, 1, 1, l)
-
-    def add_rem(Mb, Sb):
-        add = m * (Mb - Sb) / (cb + m)
-        rem = jnp.where(cb - m > 0.5,
-                        m * (Sb - Mb) / jnp.maximum(cb - m, 1.0), -Sb)
-        return add, rem
-
-    addx, remx = add_rem(mu[:, None, :, :], X[:, None, None, :])
-    addw, remw = add_rem(P[:, None, :, :], W[:, None, None, :])
-    dX = remx[..., :, None] + addx[..., None, :]         # (B, M, k, l, l)
-    dW = remw[..., :, None] + addw[..., None, :]
-    eye = jnp.eye(l, dtype=bool)[None, None, None]
-    feas = (N[:, None, :, :] >= m)[..., :, None] & ~eye
+    Pallas kernel body."""
+    colsum = N.sum(axis=0)                               # (l, B)
+    X = _col_rate(N, mu)
+    W = _col_rate(N, P)
+    Xs = X.sum(axis=0, keepdims=True)                    # (1, B)
+    Ws = W.sum(axis=0, keepdims=True)
+    ntot = colsum.sum(axis=0, keepdims=True)
+    addx, remx = _add_rem(sizes, mu, X, colsum)
+    addw, remw = _add_rem(sizes, P, W, colsum)
+    dX = _src_to_pairs(remx) + addx[:, :, None]          # (M, k, l, l, B)
+    dW = _src_to_pairs(remw) + addw[:, :, None]
+    feas = _src_to_pairs(N >= sizes) & ~_eye(*colsum.shape)
     X1 = Xs + dX
     ok = feas & (X1 > 0) & (Xs > 0)
     e_drop = jnp.where(ok, Ws / jnp.maximum(Xs, 1e-30)
@@ -151,171 +174,170 @@ def _energy_gains_body(N, mu, P, sizes, objective):
     raise ValueError(f"unknown objective {objective!r}")
 
 
+def _score_body(objective, N, mu, sizes, P=None):
+    """(gain, tie | None) under `objective` (trace-time static)."""
+    if objective == OBJ_X:
+        return _gains_body(N, mu, sizes), None
+    return _energy_gains_body(N, mu, P, sizes, objective)
+
+
+def _reduce_dirs(x, op):
+    """Reduce the (k, l_s, l_d) direction axes of (..., k, l, l, B) to
+    (..., 1, B): the leading axes first, then the sublane axis."""
+    return op(op(x, axis=(-4, -3)), axis=-2, keepdims=True)
+
+
 def _select_body(gain, tie=None):
-    """Shared move selection on a (B, M, k, l, l) gain tensor whose sizes
+    """Shared move selection on a (M, k, l, l, B) gain tensor whose sizes
     axis is the DESCENDING doubling ladder (2^(M-1), ..., 2, 1). Returns
-    (best_idx, best_gain, base_gain).
+    (best_idx, best_gain, base_gain), each (1, B).
 
     Direction (p, s, d): the steepest m=1 move — single-move GrIn's exact
-    choice. Size: the largest ladder entry whose whole prefix of doubling
-    slopes (average marginal gain of each size-doubling, via the cumulative
-    closed forms) stays >= max(second-best m=1 direction gain, 0). The
-    slope test is the run-length guard: the single-move path keeps choosing
-    this direction only while its marginal beats every alternative, so a
-    block whose marginals dip below the runner-up would overshoot into a
-    different basin (e.g. draining a whole column into a marginally faster
-    one when spreading is optimal). base_gain is the m=1 steepest gain —
-    the convergence signal.
+    choice (first index of the max in flattened (p, s, d) order). Size: the
+    largest ladder entry whose whole prefix of doubling slopes (average
+    marginal gain of each size-doubling, via the cumulative closed forms)
+    stays >= max(second-best m=1 direction gain, 0). The slope test is the
+    run-length guard: the single-move path keeps choosing this direction
+    only while its marginal beats every alternative, so a block whose
+    marginals dip below the runner-up would overshoot into a different basin
+    (e.g. draining a whole column into a marginally faster one when
+    spreading is optimal). base_gain is the m=1 steepest gain — the
+    convergence signal.
 
     With a `tie` tensor (same shape; OBJ_XE) the direction is instead the
     best TIE score among directions whose m=1 gain sits within the _XE_TIE
     float32 band of the steepest — max-X move selection with energy-drop
     tie-breaking. base_gain stays the steepest m=1 gain either way."""
-    b, msz = gain.shape[:2]
-    dirs = gain.shape[2] * gain.shape[3] * gain.shape[4]
-    g1 = gain[:, -1].reshape(b, dirs)                    # m=1 slice
-    base = jnp.max(g1, axis=1)
-    if tie is None:
-        d1 = jnp.argmax(g1, axis=1)
-    else:
-        near = g1 >= (base - _XE_TIE * (1.0 + jnp.abs(base)))[:, None]
-        d1 = jnp.argmax(jnp.where(near, tie[:, -1].reshape(b, dirs), _NEG),
-                        axis=1)
-    runner = jnp.max(jnp.where(
-        jax.nn.one_hot(d1, dirs, dtype=bool), _NEG, g1), axis=1)
-    thresh = jnp.maximum(runner, 0.0)
-    gd = gain.reshape(b, msz, dirs)
-    gsel = jnp.take_along_axis(
-        gd, d1[:, None, None], axis=2)[..., 0]           # (B, M) desc
-    gasc = gsel[:, ::-1]                                 # sizes 1, 2, 4, ...
-    sizes_asc = jnp.float32(2) ** jnp.arange(msz)
-    prev_g = jnp.concatenate(
-        [jnp.zeros((b, 1), gasc.dtype), gasc[:, :-1]], axis=1)
-    prev_s = jnp.concatenate([jnp.zeros(1), sizes_asc[:-1]])
-    slope = (gasc - prev_g) / (sizes_asc - prev_s)[None, :]
-    ok = slope >= thresh[:, None]         # infeasible -> -inf/nan -> False
-    prefix = jnp.cumprod(ok.astype(jnp.int32), axis=1).astype(bool)
-    idx_asc = jnp.maximum(prefix.sum(axis=1) - 1, 0)
-    best = jnp.take_along_axis(gasc, idx_asc[:, None], axis=1)[:, 0]
-    mi = (msz - 1) - idx_asc
-    idx = (mi * dirs + d1).astype(jnp.int32)
-    return idx, best, base
+    msz, k, l, _, b = gain.shape
+    dirs = k * l * l
+    idx = ((jax.lax.broadcasted_iota(jnp.int32, (k, l, l, b), 0) * l
+            + jax.lax.broadcasted_iota(jnp.int32, (k, l, l, b), 1)) * l
+           + jax.lax.broadcasted_iota(jnp.int32, (k, l, l, b), 2))
+    g1 = gain[msz - 1]                                   # m=1 slice
+    base = _reduce_dirs(g1, jnp.max)
+    score = g1
+    if tie is not None:
+        near = g1 >= base - _XE_TIE * (1.0 + jnp.abs(base))
+        score = jnp.where(near, tie[msz - 1], _NEG)
+    top = _reduce_dirs(score, jnp.max)
+    d1 = _reduce_dirs(jnp.where(score == top, idx, dirs), jnp.min)
+    hit = idx == d1
+    thresh = jnp.maximum(_reduce_dirs(jnp.where(hit, _NEG, g1), jnp.max), 0.0)
+    gsel = _reduce_dirs(jnp.where(hit, gain, _NEG), jnp.max)   # (M, 1, B)
+    # Walk the ladder ascending (sizes 1, 2, 4, ...): keep the longest
+    # prefix whose slopes clear the threshold (infeasible -> -inf/nan fails).
+    alive, count = None, jnp.zeros(d1.shape, jnp.int32)
+    best, prev_g, prev_s = gsel[msz - 1], 0.0, 0.0
+    for i in range(msz):
+        g = gsel[msz - 1 - i]
+        ok = (g - prev_g) / (2.0 ** i - prev_s) >= thresh
+        alive = ok if alive is None else alive & ok
+        count = count + alive.astype(jnp.int32)
+        best = jnp.where(alive, g, best)
+        prev_g, prev_s = g, 2.0 ** i
+    mi = (msz - 1) - jnp.maximum(count - 1, 0)
+    return mi * dirs + d1, best, base
 
 
+def _to_lanes(x):
+    """(B, k, l) -> batch-last (k, l, B) float32."""
+    return jnp.transpose(jnp.asarray(x, jnp.float32), (1, 2, 0))
+
+
+def _gains_to_rows(gain):
+    """(M, k, l, l, B) -> (B, F) in (m, p, s, d) flattened order."""
+    return jnp.moveaxis(gain, -1, 0).reshape(gain.shape[-1], -1)
+
+
+@jax.jit
 def block_move_gains_ref(N, mu, sizes):
     """Pure-jnp reference: (B, M, k, l, l) move gains."""
-    return _gains_body(jnp.asarray(N, jnp.float32),
-                       jnp.asarray(mu, jnp.float32),
-                       jnp.asarray(sizes, jnp.float32))
+    sizes = jnp.asarray(sizes, jnp.float32)
+    gain = _gains_body(_to_lanes(N), _to_lanes(mu),
+                       sizes.reshape(-1, 1, 1, 1))
+    return jnp.moveaxis(gain, -1, 0)
 
 
-def _kernel(n_ref, mu_ref, sz_ref, g_ref, bi_ref, bg_ref, b1_ref):
-    gain = _gains_body(n_ref[...], mu_ref[...], sz_ref[...])
-    g_ref[...] = gain.reshape(gain.shape[0], -1)         # (Bt, F)
-    bi, bg, base = _select_body(gain)
-    bi_ref[...] = bi[:, None]
-    bg_ref[...] = bg[:, None]
-    b1_ref[...] = base[:, None]
+def _kernel(objective, return_gains, *refs):
+    n_in = 3 if objective == OBJ_X else 4
+    ins, outs = refs[:n_in], refs[n_in:]
+    gain, tie = _score_body(objective, *(r[...] for r in ins))
+    if return_gains:
+        outs[0][...] = gain
+        outs = outs[1:]
+    for ref, v in zip(outs, _select_body(gain, tie)):
+        ref[...] = v
 
 
-def _kernel_select(n_ref, mu_ref, sz_ref, bi_ref, bg_ref, b1_ref):
-    """Selection-only variant: the solver loop discards the gains tensor, so
-    skipping its output saves the (Bt, F) write on every solver step."""
-    bi, bg, base = _select_body(
-        _gains_body(n_ref[...], mu_ref[...], sz_ref[...]))
-    bi_ref[...] = bi[:, None]
-    bg_ref[...] = bg[:, None]
-    b1_ref[...] = base[:, None]
-
-
-def _kernel_obj(objective, n_ref, mu_ref, p_ref, sz_ref, g_ref, bi_ref,
-                bg_ref, b1_ref):
-    """Energy-objective kernel: same structure as `_kernel` plus the power
-    matrix input; `objective` is bound trace-time via functools.partial."""
-    gain, tie = _energy_gains_body(n_ref[...], mu_ref[...], p_ref[...],
-                                   sz_ref[...], objective)
-    g_ref[...] = gain.reshape(gain.shape[0], -1)
-    bi, bg, base = _select_body(gain, tie)
-    bi_ref[...] = bi[:, None]
-    bg_ref[...] = bg[:, None]
-    b1_ref[...] = base[:, None]
-
-
-def _kernel_select_obj(objective, n_ref, mu_ref, p_ref, sz_ref, bi_ref,
-                       bg_ref, b1_ref):
-    gain, tie = _energy_gains_body(n_ref[...], mu_ref[...], p_ref[...],
-                                   sz_ref[...], objective)
-    bi, bg, base = _select_body(gain, tie)
-    bi_ref[...] = bi[:, None]
-    bg_ref[...] = bg[:, None]
-    b1_ref[...] = base[:, None]
-
-
-def block_move_gains_pallas(N, mu, sizes, *, block_b: int = 8,
-                            interpret: bool = False,
+def block_move_gains_pallas(N, mu, sizes, *, interpret: bool = False,
                             return_gains: bool = True,
                             P=None, objective: int = OBJ_X):
-    """Pallas path: grid over B-tiles; returns (gains (B, F) | None,
-    best_idx, best_gain, base_gain).
+    """Pallas path: grid over lane tiles of the batch; returns
+    (gains (B, F) | None, best_idx, best_gain, base_gain).
 
-    B is padded up to a block multiple with empty states (colsum 0 -> every
-    move infeasible, gains all -inf) and the pad is sliced away. With
-    `return_gains=False` the gains tensor is never written — the solver
-    loop only consumes the selection. Energy objectives (OBJ_XE/E/EDP/
-    E_GUARD) additionally stream the power matrix P through VMEM; OBJ_X
-    keeps the original two-input kernel (identical compiled program).
+    B rides the lane axis: one tile when B <= _LANES, else B is padded up
+    to a _LANES multiple with empty states (colsum 0 -> every move
+    infeasible) and the pad is sliced away. With `return_gains=False` the
+    gains tensor is never written — the solver loop only consumes the
+    selection. Energy objectives (OBJ_XE/E/EDP/E_GUARD) additionally stream
+    the power matrix P through VMEM.
     """
-    N = jnp.asarray(N, jnp.float32)
-    mu = jnp.asarray(mu, jnp.float32)
+    b, k, l = jnp.shape(N)
     sizes = jnp.asarray(sizes, jnp.float32)
-    b, k, l = N.shape
     msz = sizes.shape[0]
-    f = msz * k * l * l
-    bt = min(block_b, b)
-    pad = (-b) % bt
+    inputs = [N, mu]
     if objective != OBJ_X:
         if P is None:
             raise ValueError("energy objectives need the power matrix P")
-        P = jnp.broadcast_to(jnp.asarray(P, jnp.float32), N.shape)
-    if pad:
-        N = jnp.pad(N, ((0, pad), (0, 0), (0, 0)))
-        mu = jnp.pad(mu, ((0, pad), (0, 0), (0, 0)))
-        if objective != OBJ_X:
-            P = jnp.pad(P, ((0, pad), (0, 0), (0, 0)))
+        inputs.append(jnp.broadcast_to(jnp.asarray(P, jnp.float32),
+                                       jnp.shape(N)))
+    bt = min(b, _LANES)
+    pad = (-b) % bt
+    inputs = [jnp.pad(_to_lanes(x), ((0, 0), (0, 0), (0, pad)))
+              for x in inputs]
+    # kernel argument order follows `_score_body`: N, mu, sizes[, P]
+    inputs.insert(2, sizes.reshape(msz, 1, 1, 1))
     bp = b + pad
-    sel_specs = [pl.BlockSpec((bt, 1), lambda i: (i, 0))] * 3
-    sel_shapes = [jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-                  jax.ShapeDtypeStruct((bp, 1), jnp.float32),
-                  jax.ShapeDtypeStruct((bp, 1), jnp.float32)]
+    kl_spec = pl.BlockSpec((k, l, bt), lambda i: (0, 0, i))
+    in_specs = [kl_spec, kl_spec,
+                pl.BlockSpec((msz, 1, 1, 1), lambda i: (0, 0, 0, 0))]
+    in_specs += [kl_spec] * (len(inputs) - 3)
+    row_spec = pl.BlockSpec((1, bt), lambda i: (0, i))
+    out_specs = [row_spec] * 3
+    out_shape = [jax.ShapeDtypeStruct((1, bp), jnp.int32),
+                 jax.ShapeDtypeStruct((1, bp), jnp.float32),
+                 jax.ShapeDtypeStruct((1, bp), jnp.float32)]
     if return_gains:
-        gains_spec = [pl.BlockSpec((bt, f), lambda i: (i, 0))]
-        gains_shape = [jax.ShapeDtypeStruct((bp, f), jnp.float32)]
-        kernel = (_kernel if objective == OBJ_X
-                  else functools.partial(_kernel_obj, objective))
-    else:
-        gains_spec, gains_shape = [], []
-        kernel = (_kernel_select if objective == OBJ_X
-                  else functools.partial(_kernel_select_obj, objective))
-    kl_spec = pl.BlockSpec((bt, k, l), lambda i: (i, 0, 0))
-    in_specs = [kl_spec, kl_spec]
-    inputs = [N, mu]
-    if objective != OBJ_X:
-        in_specs.append(kl_spec)
-        inputs.append(P)
-    in_specs.append(pl.BlockSpec((msz,), lambda i: (0,)))
-    inputs.append(sizes)
+        out_specs.insert(0, pl.BlockSpec((msz, k, l, l, bt),
+                                         lambda i: (0, 0, 0, 0, i)))
+        out_shape.insert(0, jax.ShapeDtypeStruct((msz, k, l, l, bp),
+                                                  jnp.float32))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, objective, return_gains),
         grid=(bp // bt,),
         in_specs=in_specs,
-        out_specs=gains_spec + sel_specs,
-        out_shape=gains_shape + sel_shapes,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="grin_block_move_gains",
     )(*inputs)
-    gains = out[0][:b] if return_gains else None
-    bi, bg, base = out[-3:]
-    return gains, bi[:b, 0], bg[:b, 0], base[:b, 0]
+    gains = _gains_to_rows(out[0][..., :b]) if return_gains else None
+    bi, bg, base = (x[0, :b] for x in out[-3:])
+    return gains, bi, bg, base
+
+
+@functools.partial(jax.jit, static_argnames=("objective", "return_gains"))
+def _scores_ref(N, mu, sizes, P, objective, return_gains):
+    """jnp path of `block_move_scores`, jitted so that eager callers see the
+    same numbers as the solver loop (XLA fuses; eager evaluation rounds
+    each op on its own)."""
+    sizes = jnp.asarray(sizes, jnp.float32).reshape(-1, 1, 1, 1)
+    gain, tie = _score_body(objective, _to_lanes(N), _to_lanes(mu), sizes,
+                            None if P is None else _to_lanes(P))
+    bi, bg, base = (x[0] for x in _select_body(gain, tie))
+    return (_gains_to_rows(gain) if return_gains else None), bi, bg, base
 
 
 def block_move_scores(N, mu, sizes, *, use_kernel: bool | None = None,
@@ -332,35 +354,30 @@ def block_move_scores(N, mu, sizes, *, use_kernel: bool | None = None,
     signal. `objective` switches what the gains measure (throughput, energy
     drop, EDP drop, or throughput with energy tie-breaks — see the OBJ_*
     constants); all energy objectives need `P`. `return_gains=False` skips
-    materializing the gains tensor (the solver's hot loop). `use_kernel=None`
-    picks the Pallas kernel on TPU (or under REPRO_PALLAS_INTERPRET=1) and
-    the jnp reference elsewhere; both produce bit-identical outputs.
+    materializing the gains tensor (the solver's hot loop).
+
+    `use_kernel=None` picks the compiled Pallas kernel on TPU and the jnp
+    reference elsewhere; `use_kernel=True` asks for the kernel. Interpret
+    mode runs only when REPRO_PALLAS_INTERPRET=1 asks for it: off-TPU a
+    compiled kernel fails rather than fall back (see `kernel_mode`).
     """
-    if use_kernel is None:
-        use_kernel = _use_pallas() or _interpret()
-    if use_kernel:
-        import jax.core as jcore
-        from repro.obs.profile import span as _obs_span
-        # span only at the host level: under a jit trace (abstract N) a
-        # wall-clock pair would time tracing, not the kernel
-        if not isinstance(N, jcore.Tracer):
-            with _obs_span("pallas_gain_kernel") as sp:
-                return sp.ready(block_move_gains_pallas(
-                    N, mu, sizes,
-                    interpret=_interpret() or not _use_pallas(),
-                    return_gains=return_gains, P=P, objective=objective))
-        return block_move_gains_pallas(
-            N, mu, sizes, interpret=_interpret() or not _use_pallas(),
-            return_gains=return_gains, P=P, objective=objective)
-    if objective == OBJ_X:
-        gains, tie = block_move_gains_ref(N, mu, sizes), None
-    else:
-        if P is None:
-            raise ValueError("energy objectives need the power matrix P")
-        gains, tie = _energy_gains_body(
-            jnp.asarray(N, jnp.float32), jnp.asarray(mu, jnp.float32),
-            jnp.broadcast_to(jnp.asarray(P, jnp.float32), jnp.shape(N)),
-            jnp.asarray(sizes, jnp.float32), objective)
-    bi, bg, base = _select_body(gains, tie)
-    return (gains.reshape(gains.shape[0], -1) if return_gains else None,
-            bi, bg, base)
+    mode = kernel_mode(use_kernel)
+    if mode == "jnp-reference":
+        if objective != OBJ_X:
+            if P is None:
+                raise ValueError("energy objectives need the power matrix P")
+            P = jnp.broadcast_to(jnp.asarray(P, jnp.float32), jnp.shape(N))
+        return _scores_ref(N, mu, sizes, None if objective == OBJ_X else P,
+                           objective, return_gains)
+    import jax.core as jcore
+    from repro.obs.profile import span as _obs_span
+    call = functools.partial(block_move_gains_pallas, N, mu, sizes,
+                             interpret=mode == "pallas-interpret",
+                             return_gains=return_gains, P=P,
+                             objective=objective)
+    # span only at the host level: under a jit trace (abstract N) a
+    # wall-clock pair would time tracing, not the kernel
+    if isinstance(N, jcore.Tracer):
+        return call()
+    with _obs_span("pallas_gain_kernel") as sp:
+        return sp.ready(call())

@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams to CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, la_ref, b_ref, y_ref, s_ref, *, chunk: int):
     ci = pl.program_id(1)
@@ -95,7 +91,7 @@ def ssd_scan_pallas(q, k, v, log_a, beta, *, chunk=256, interpret=False):
         out_specs=pl.BlockSpec((None, chunk, dv), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],  # carried state
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, la2, b2)

@@ -1,6 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Batched prefill + greedy decode with the ServeEngine; optionally schedules a
+Batched prefill + greedy decode with the ServeEngine at the architecture's
+published width (``--smoke``: the reduced CPU width); optionally schedules a
 mixed request stream across two pools with the paper's CAB policy
 (--heterogeneous), or replays an open request trace through GrIn-P placement
 plus SLO admission control (--traffic).
@@ -16,16 +17,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
-from repro.serve.engine import ServeEngine, request_service_fns
+from repro.serve.engine import (ServeEngine, init_serving_params,
+                                request_service_fns)
 
 _TRACE = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                       "examples", "data", "serve_trace.json")
 
 
-def main() -> None:
+def _parse(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced smoke_config width (CPU runs); "
+                         "default is the published width")
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--steps", type=int, default=16)
@@ -44,13 +50,23 @@ def main() -> None:
                     help="write a Chrome trace of scheduler + admission "
                          "decisions here (--traffic; open in Perfetto or "
                          "summarize with tools/trace_view.py)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = smoke_config(get_arch(args.arch))
+
+def run(argv=None) -> dict:
+    """Serve once as the command line says; returns what was served:
+    the engine and its batch, the generated tokens, and (with
+    --heterogeneous) the measured mu and per-policy closed-loop rates."""
+    args = _parse(argv)
+    use_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.smoke:
+        cfg = smoke_config(cfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(model, params,
+    engine = ServeEngine(model, init_serving_params(model,
+                                                    jax.random.PRNGKey(0)),
                          max_len=args.prompt_len + args.steps + 8)
+    result: dict = {"cfg": cfg, "engine": engine}
 
     key = jax.random.PRNGKey(1)
     if cfg.family == "audio":
@@ -66,12 +82,13 @@ def main() -> None:
             key, (args.batch, cfg.n_patches, cfg.d_model), jnp.bfloat16)
 
     t0 = time.time()
-    out = engine.generate(batch, steps=args.steps)
+    out = jax.block_until_ready(engine.generate(batch, steps=args.steps))
     dt = time.time() - t0
     n_tok = int(np.prod(out.shape))
     print(f"[serve] {cfg.name}: generated {out.shape} tokens in {dt:.2f}s "
           f"({n_tok/dt:.1f} tok/s incl. compile)")
     print("[serve] sample:", np.asarray(out)[0].tolist()[:16])
+    result.update(batch=batch, tokens=out)
 
     if args.heterogeneous:
         from repro.core import classify_2x2
@@ -84,10 +101,12 @@ def main() -> None:
         print(f"[serve] measured mu:\n{np.round(mu, 2)} "
               f"({classify_2x2(mu).value})")
         types = [0] * 4 + [1] * 4
+        result.update(mu=mu, throughput={})
         for name in ("cab", "lb"):
             sched = SchedulerCore(get_policy(name), mu)
             m = VirtualTimeCluster(fns).run_closed(
                 sched, types, n_completions=60, warmup=10)
+            result["throughput"][name] = m.throughput
             print(f"[serve] {sched.name}: X={m.throughput:.2f} req/s")
 
     if args.traffic:
@@ -137,7 +156,8 @@ def main() -> None:
                   f"p50 {m.class_p50[c]:.3f}s p99 {m.class_p99[c]:.3f}s "
                   f"SLO-met {m.class_deadline_met[c]:.2f} "
                   f"limit {m.limits[c]:.0f}")
+    return result
 
 
 if __name__ == "__main__":
-    main()
+    run()

@@ -200,7 +200,6 @@ def apply_moe(p, x, cfg: ModelConfig):
         # their weights are replicated anyway and the global scatter reshards.
         return _apply_moe_local(p, x, cfg)
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     dp = shctx._CTX["rules"].get("dp") or ("data",)
 
     def local_fn(xl, router, w_in, w_out):
@@ -208,10 +207,10 @@ def apply_moe(p, x, cfg: ModelConfig):
             {"router": router, "w_in": w_in, "w_out": w_out}, xl, cfg)
         return y, jax.lax.pmean(aux, axis_name=dp)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(), P(), P()),
-        out_specs=(P(dp, None, None), P()), check_rep=False,
+        out_specs=(P(dp, None, None), P()), check_vma=False,
     )(x, p["router"], p["w_in"], p["w_out"])
     return y, aux
 

@@ -1,10 +1,11 @@
 """Run metadata: make every perf number attributable.
 
 `run_meta()` captures the execution substrate a measurement ran on — jax
-backend, Pallas kernel mode (compiled TPU kernel vs interpret-mode vs the
-jnp reference), compute dtype, versions. `benchmarks.common.save_json`
-attaches it to every BENCH payload and the engines stamp it onto
-SimMetrics rows, so a "1.4x faster" claim always says 1.4x faster *where*.
+backend, device kind and count, Pallas kernel mode (compiled TPU kernel vs
+interpret-mode vs the jnp reference), compute dtype, versions.
+`benchmarks.common.save_json` attaches it to every BENCH payload and the
+engines stamp it onto SimMetrics rows, so a "1.4x faster" claim always says
+1.4x faster *where*.
 """
 from __future__ import annotations
 
@@ -12,15 +13,11 @@ import platform
 
 
 def kernel_mode() -> str:
-    """Which gain-scoring path `repro.kernels.grin_moves` will take:
-    "pallas-compiled" (real TPU), "pallas-interpret"
-    (REPRO_PALLAS_INTERPRET=1), or "jnp-reference"."""
-    from repro.kernels.grin_moves import _interpret, _use_pallas
-    if _use_pallas():
-        return "pallas-compiled"
-    if _interpret():
-        return "pallas-interpret"
-    return "jnp-reference"
+    """Which gain-scoring path `repro.kernels.grin_moves` takes by default:
+    "pallas-compiled" (TPU), "pallas-interpret" (REPRO_PALLAS_INTERPRET=1),
+    or "jnp-reference"."""
+    from repro.kernels.grin_moves import kernel_mode as _mode
+    return _mode()
 
 
 def run_meta() -> dict:
@@ -29,6 +26,8 @@ def run_meta() -> dict:
     return {
         "jax_backend": jax.default_backend(),
         "jax_version": jax.__version__,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
         "kernel_mode": kernel_mode(),
         "dtype": "float32",              # the engines' device state dtype
         "python": platform.python_version(),

@@ -31,6 +31,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding
 
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ckpt
 
 log = logging.getLogger("repro.ft")
@@ -98,7 +99,7 @@ class ElasticMeshManager:
                 model = m
                 break
         shape = (n // model, model)
-        return jax.make_mesh(shape, self.axis_names[-2:])
+        return make_mesh(shape, self.axis_names[-2:])
 
     def reshard(self, tree, spec_tree, mesh: Mesh):
         return jax.tree.map(

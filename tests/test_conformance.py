@@ -90,7 +90,9 @@ PMU_BASE = [np.random.default_rng(21).uniform(1, 30, size=(2, 3)),
 PCLASS_MIXES = np.array([[[3, 2], [7, 8]],       # (M, C, k): small latency
                          [[2, 4], [9, 5]]])      # class + a big batch class
 PSEEDS = [0, 1]
-P_COMP, P_WARM = 3000, 600
+# strict priority starves the batch class for long stretches, so its
+# per-class rates need the longer run to settle
+P_COMP, P_WARM = 6000, 1200
 P_PT_TOL, P_MEAN_TOL = 0.2, 0.08
 
 

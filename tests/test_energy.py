@@ -166,9 +166,10 @@ def test_batched_objectives_converge_and_order_sensibly():
         grin_solve_batch_jax(mus, mixes, objective="warp")
 
 
-def test_energy_objective_kernel_bit_matches_reference():
+def test_energy_objective_kernel_bit_matches_reference(monkeypatch):
     """The Pallas kernel (interpret mode) and the jnp reference agree BIT
     for every energy objective — gains, selection, and convergence signal."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     rng = np.random.default_rng(1)
     for b, k, l, m in [(5, 3, 3, 6), (9, 4, 6, 8)]:
         N = rng.integers(0, 20, size=(b, k, l)).astype(np.float32)

@@ -421,8 +421,10 @@ def test_fault_host_loop_records_breakpoints():
 
 def test_run_meta_keys_and_metrics_are_stamped():
     meta = run_meta()
-    assert set(meta) >= {"jax_backend", "jax_version", "kernel_mode",
-                         "dtype", "python", "platform"}
+    assert set(meta) >= {"jax_backend", "jax_version", "device_kind",
+                         "device_count", "kernel_mode", "dtype", "python",
+                         "platform"}
+    assert meta["device_count"] >= 1 and meta["device_kind"]
     assert meta["dtype"] == "float32"
     assert meta["kernel_mode"] in ("pallas-compiled", "pallas-interpret",
                                    "jnp-reference")

@@ -2,6 +2,7 @@
 subprocess check that a sharded train step compiles AND matches the
 single-device result numerically (DP/TP equivalence)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -75,7 +76,8 @@ _SUBPROC = textwrap.dedent("""
     s_ref, met_ref = jax.jit(step)(state0, batch)
 
     # 2x4 mesh (data=2, model=4)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         state1 = init_train_state(m, key, opt)
         pspecs = param_pspec_tree(
@@ -106,8 +108,9 @@ def test_sharded_train_step_matches_single_device():
     out = subprocess.run(
         [sys.executable, "-c", _SUBPROC], capture_output=True, text=True,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        timeout=600, cwd="/root/repo")
+             "HOME": os.environ.get("HOME", "/"), "JAX_PLATFORMS": "cpu"},
+        timeout=600, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["loss_ref"] == pytest.approx(res["loss_mesh"], rel=1e-4)

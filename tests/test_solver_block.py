@@ -13,7 +13,8 @@ from repro.core import (delta_x_add, delta_x_add_block, delta_x_remove,
                         grin_solve_batch_jax, grin_solve_jax,
                         random_affinity_matrix, system_throughput)
 from repro.kernels.grin_moves import (block_move_gains_pallas,
-                                      block_move_gains_ref, block_move_scores)
+                                      block_move_gains_ref, block_move_scores,
+                                      kernel_mode)
 from repro.sched import (SchedulerCore, solve_targets_grid_jax,
                          solve_targets_jax)
 from repro.sched.api import _repair_targets
@@ -268,9 +269,10 @@ def test_gain_kernel_bit_matches_reference():
                                       gasc[np.arange(b), idx_asc])
 
 
-def test_solver_kernel_path_bit_matches_jnp_path():
+def test_solver_kernel_path_bit_matches_jnp_path(monkeypatch):
     """The whole batched solve is bit-identical whichever scoring backend
     runs inside the loop (interpret-mode Pallas vs jnp reference)."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     rng = np.random.default_rng(1)
     mu = random_affinity_matrix(rng, 4, 5)
     mixes = rng.multinomial(120, [0.25] * 4, size=6)
@@ -279,3 +281,24 @@ def test_solver_kernel_path_bit_matches_jnp_path():
     np.testing.assert_array_equal(np.asarray(N1), np.asarray(N2))
     np.testing.assert_array_equal(np.asarray(x1), np.asarray(x2))
     np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
+
+
+def test_kernel_runs_interpreted_only_when_asked(monkeypatch):
+    """Off-TPU the auto choice is the jnp path; `use_kernel=True` asks for
+    the compiled kernel and fails there instead of quietly interpreting;
+    REPRO_PALLAS_INTERPRET=1 is the one way into interpret mode."""
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert kernel_mode() == "jnp-reference"
+    assert kernel_mode(False) == "jnp-reference"
+    assert kernel_mode(True) == "pallas-compiled"
+    N = np.ones((2, 2, 2), np.float32)
+    sizes = np.array([2.0, 1.0], np.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        block_move_scores(N, N, sizes, use_kernel=True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert kernel_mode() == kernel_mode(True) == "pallas-interpret"
+    assert kernel_mode(False) == "jnp-reference"
+    ref = block_move_scores(N, N, sizes, use_kernel=False)
+    pal = block_move_scores(N, N, sizes, use_kernel=True)
+    for r, p_ in zip(ref, pal):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(p_))
