@@ -345,7 +345,8 @@ def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, kernel, objective):
     B, k, l = mus.shape
     # Largest size first: argmax ties prefer the biggest improving block.
     sizes = jnp.float32(2) ** jnp.arange(n_sizes - 1, -1, -1)
-    N0 = jax.vmap(_grin_init_jax)(mus, mixes)
+    with jax.named_scope("grin.init"):
+        N0 = jax.vmap(_grin_init_jax)(mus, mixes)
     cap = (jnp.int32(max_moves) if max_moves is not None
            else mixes.sum(axis=1).max().astype(jnp.int32) + 64)
 
@@ -384,13 +385,15 @@ def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, kernel, objective):
             cond, body, (N0_, jnp.ones(B, bool), moves0, jnp.int32(0)))
         return N, moves, ~active
 
-    N, moves, conv = run_phase(N0, jnp.zeros(B, jnp.int32), objective)
-    if objective == OBJ_XE:
-        # Phase 2 of max-X-E: slide along the X plateau (moves whose dX
-        # stays within float32 noise of zero) toward lower energy.
-        N, moves, conv2 = run_phase(N, moves, OBJ_E_GUARD)
-        conv = conv & conv2
-    xs = jax.vmap(system_throughput_jax)(N, mus)
+    with jax.named_scope("grin.loop"):
+        N, moves, conv = run_phase(N0, jnp.zeros(B, jnp.int32), objective)
+        if objective == OBJ_XE:
+            # Phase 2 of max-X-E: slide along the X plateau (moves whose dX
+            # stays within float32 noise of zero) toward lower energy.
+            N, moves, conv2 = run_phase(N, moves, OBJ_E_GUARD)
+            conv = conv & conv2
+    with jax.named_scope("grin.final"):
+        xs = jax.vmap(system_throughput_jax)(N, mus)
     return N, xs, conv, moves
 
 
@@ -438,31 +441,33 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
     affinities but watts stay class-blind, so they pass the physical tile
     here instead of letting P derive from the weighted mu.
     """
-    mixes = jnp.asarray(n_tasks_batch, dtype=jnp.float32)
-    mus = jnp.asarray(mu, dtype=jnp.float32)
-    if mixes.ndim != 2:
-        raise ValueError(f"n_tasks_batch must be (B, k); got {mixes.shape}")
-    B, k = mixes.shape
-    if mus.ndim == 2:
-        mus = jnp.broadcast_to(mus, (B,) + mus.shape)
-    if mus.ndim != 3 or mus.shape[:2] != (B, k):
-        raise ValueError(f"mu must be (k={k}, l) or (B={B}, k={k}, l); got "
-                         f"{tuple(jnp.shape(mu))}")
-    obj = _objective_id(objective)
-    from repro.kernels.grin_moves import OBJ_X
-    if obj == OBJ_X:
-        Ps = mus            # unused by the throughput objective
-    elif P is not None:
-        Ps = jnp.broadcast_to(jnp.asarray(P, jnp.float32), mus.shape)
-    else:
-        from repro.core.affinity import PROPORTIONAL_POWER
-        from repro.core.energy import power_matrix_jax
-        Ps = power_matrix_jax(mus, power or PROPORTIONAL_POWER)
-    if n_sizes is None:
-        n_sizes = len(_ladder(int(np.asarray(n_tasks_batch).sum(axis=1).max())))
-    from repro.kernels.grin_moves import kernel_mode
+    from repro.kernels.grin_moves import OBJ_X, kernel_mode
     from repro.obs.profile import span as _obs_span
-    with _obs_span("grin_solve_batch_jax") as sp:
-        return sp.ready(_grin_block_core(mus, mixes, Ps, int(n_sizes),
-                                         max_moves, kernel_mode(use_kernel),
-                                         obj))
+    with _obs_span("repro.grin.prep"):
+        mixes = jnp.asarray(n_tasks_batch, dtype=jnp.float32)
+        mus = jnp.asarray(mu, dtype=jnp.float32)
+        if mixes.ndim != 2:
+            raise ValueError(f"n_tasks_batch must be (B, k); got "
+                             f"{mixes.shape}")
+        B, k = mixes.shape
+        if mus.ndim == 2:
+            mus = jnp.broadcast_to(mus, (B,) + mus.shape)
+        if mus.ndim != 3 or mus.shape[:2] != (B, k):
+            raise ValueError(f"mu must be (k={k}, l) or (B={B}, k={k}, l); "
+                             f"got {tuple(jnp.shape(mu))}")
+        obj = _objective_id(objective)
+        if obj == OBJ_X:
+            Ps = mus            # unused by the throughput objective
+        elif P is not None:
+            Ps = jnp.broadcast_to(jnp.asarray(P, jnp.float32), mus.shape)
+        else:
+            from repro.core.affinity import PROPORTIONAL_POWER
+            from repro.core.energy import power_matrix_jax
+            Ps = power_matrix_jax(mus, power or PROPORTIONAL_POWER)
+        if n_sizes is None:
+            n_sizes = len(_ladder(int(np.asarray(n_tasks_batch)
+                                      .sum(axis=1).max())))
+        kernel = kernel_mode(use_kernel)
+    with _obs_span("repro.grin.dispatch"):
+        return _grin_block_core(mus, mixes, Ps, int(n_sizes), max_moves,
+                                kernel, obj)

@@ -369,15 +369,7 @@ def block_move_scores(N, mu, sizes, *, use_kernel: bool | None = None,
             P = jnp.broadcast_to(jnp.asarray(P, jnp.float32), jnp.shape(N))
         return _scores_ref(N, mu, sizes, None if objective == OBJ_X else P,
                            objective, return_gains)
-    import jax.core as jcore
-    from repro.obs.profile import span as _obs_span
-    call = functools.partial(block_move_gains_pallas, N, mu, sizes,
-                             interpret=mode == "pallas-interpret",
-                             return_gains=return_gains, P=P,
-                             objective=objective)
-    # span only at the host level: under a jit trace (abstract N) a
-    # wall-clock pair would time tracing, not the kernel
-    if isinstance(N, jcore.Tracer):
-        return call()
-    with _obs_span("pallas_gain_kernel") as sp:
-        return sp.ready(call())
+    return block_move_gains_pallas(N, mu, sizes,
+                                   interpret=mode == "pallas-interpret",
+                                   return_gains=return_gains, P=P,
+                                   objective=objective)

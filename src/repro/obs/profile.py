@@ -1,18 +1,28 @@
-"""Wall-clock profiling spans for the hot solver paths.
+"""Program spans and counters, on the profiler's clock.
 
-JAX dispatch is asynchronous: a naive `time.perf_counter` pair around a
-device call times the *enqueue*, not the work. The profiler's `span`
-context therefore calls `jax.block_until_ready` on whatever the caller
-hands to `span.ready(...)` before closing the span — but ONLY when
-profiling is enabled, so the production path keeps its async pipelining.
+`span(name)` is the program's one span API. Every span is named
+`repro.<layer>.<phase>` and lands in two places:
 
-Off by default. `enable_profiling()` flips a module-level flag checked
-once per instrumented call; disabled cost is one attribute read. The
-instrumented entry points (PR 10): `solve_targets_jax`,
-`solve_targets_grid_jax`, `grin_solve_batch_jax`,
-`SchedulerCore.route_many`, and the Pallas gain-kernel host entry
-(`block_move_scores`, skipped under a jit trace where wall time is
-meaningless).
+  * any `jax.profiler` trace that is running (`jax.profiler.start_trace`,
+    the profiler server): the span opens a `jax.profiler.TraceAnnotation`,
+    so it sits in the same xplane as the device ops, on the same clock, and
+    a reducer can line a host phase up with the device work inside it;
+  * the in-process `Profiler`'s ring buffer, when it is enabled
+    (`enable_profiling()`), timed with `time.perf_counter`.
+
+With neither active a span costs one flag test and one call to the
+tracer's `is_enabled` (about 1 us with the `with` statement), `count` and
+`ready` do nothing, and no device work is waited on.
+
+Counters are arguments of the span: `sp.count(rows=3)` writes them as
+stats of the span's trace event (nothing is recorded outside a trace).
+Counters that cost a device-to-host transfer are computed only under
+`tracing_active()`.
+
+`sp.ready(x)` blocks on device work (and returns x) only when the
+`Profiler` is enabled, so its host-clock spans time execution rather than
+the enqueue; under a trace alone it is the identity, and the device trace
+says when the work ran.
 
     >>> from repro.obs import enable_profiling, get_profiler
     >>> enable_profiling()
@@ -27,7 +37,15 @@ import dataclasses
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 _MAX_SPANS = 16384
+
+
+def tracing_active() -> bool:
+    """True while a profiler trace records host spans: the gate for
+    counters that cost a transfer."""
+    return TraceAnnotation.is_enabled()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,34 +58,51 @@ class ProfileSpan:
 
 
 class _ActiveSpan:
-    """Context manager for one live span; `ready(x)` blocks on device work
-    (and returns x) so the span covers execution, not just dispatch."""
+    """One live span: a trace annotation while tracing, a ring-buffer entry
+    while `profiler` is set (enabled)."""
 
-    __slots__ = ("_profiler", "name", "_t0")
+    __slots__ = ("_profiler", "name", "_t0", "_ta")
 
-    def __init__(self, profiler: "Profiler", name: str):
+    def __init__(self, profiler: "Profiler | None", name: str):
         self._profiler = profiler
         self.name = name
+        self._ta = TraceAnnotation(name) if tracing_active() else None
+
+    def count(self, **counters) -> None:
+        """Attach counters to the span's trace event (a no-op untraced)."""
+        if self._ta is not None:
+            self._ta.set_metadata(**counters)
 
     def ready(self, x):
+        if self._profiler is None:
+            return x
         import jax
         return jax.block_until_ready(x)
 
     def __enter__(self):
+        if self._ta is not None:
+            self._ta.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._profiler._push(ProfileSpan(
-            name=self.name, t0=self._t0,
-            dur=time.perf_counter() - self._t0))
+        if self._profiler is not None:
+            self._profiler._push(ProfileSpan(
+                name=self.name, t0=self._t0,
+                dur=time.perf_counter() - self._t0))
+        if self._ta is not None:
+            self._ta.__exit__(*exc)
         return False
 
 
 class _NullSpan:
-    """Disabled-path span: no timing, `ready` is the identity."""
+    """Span with nothing recording: no timing; `count` and `ready` do
+    nothing."""
 
     __slots__ = ()
+
+    def count(self, **counters) -> None:
+        pass
 
     def ready(self, x):
         return x
@@ -93,10 +128,13 @@ class Profiler:
         self._spans.append(span)
 
     def span(self, name: str):
-        """`with profiler.span("solve"): ...` — a no-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _ActiveSpan(self, name)
+        """`with profiler.span("repro.x.y"): ...`; also lands in a running
+        trace. A no-op when neither records."""
+        if self.enabled:
+            return _ActiveSpan(self, name)
+        if tracing_active():
+            return _ActiveSpan(None, name)
+        return _NULL_SPAN
 
     @property
     def spans(self) -> list[ProfileSpan]:
@@ -139,9 +177,7 @@ def enable_profiling(on: bool = True) -> Profiler:
 def span(name: str):
     """Module-level span against the default profiler (the instrumented
     library call sites use this)."""
-    if not _PROFILER.enabled:
-        return _NULL_SPAN
-    return _ActiveSpan(_PROFILER, name)
+    return _PROFILER.span(name)
 
 
 @contextlib.contextmanager
@@ -156,4 +192,4 @@ def profile_block(name: str):
 
 
 __all__ = ["Profiler", "ProfileSpan", "get_profiler", "enable_profiling",
-           "span", "profile_block"]
+           "span", "profile_block", "tracing_active"]

@@ -50,7 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.obs.profile import span as _obs_span
+from repro.obs.profile import span as _obs_span, tracing_active
 
 from repro.core.affinity import PROPORTIONAL_POWER, PowerModel
 from repro.core.cab import cab_target_state
@@ -396,12 +396,15 @@ def _repair_targets(raw: np.ndarray, mixes: np.ndarray) -> np.ndarray:
     `.round()` can drift a row off its task count on large mixes; rows that
     drift are re-rounded by largest remainder (the same repair SLSQP uses).
     """
-    raw = np.asarray(raw, dtype=np.float64)
-    mixes = np.asarray(mixes, dtype=np.int64)
-    out = raw.round().astype(np.int64)
-    for b in np.flatnonzero((out.sum(axis=-1) != mixes).any(axis=-1)):
-        out[b] = round_largest_remainder(raw[b], mixes[b])
-    return np.maximum(out, 0)
+    with _obs_span("repro.grid.repair") as sp:
+        raw = np.asarray(raw, dtype=np.float64)
+        mixes = np.asarray(mixes, dtype=np.int64)
+        out = raw.round().astype(np.int64)
+        drifted = np.flatnonzero((out.sum(axis=-1) != mixes).any(axis=-1))
+        for b in drifted:
+            out[b] = round_largest_remainder(raw[b], mixes[b])
+        sp.count(rows=drifted.size)
+        return np.maximum(out, 0)
 
 
 def physical_power_matrix(policy: Policy, mus: np.ndarray):
@@ -445,7 +448,7 @@ def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
     if mixes.ndim != 2 or mixes.shape[1] != mu.shape[0]:
         raise ValueError(f"n_tasks_batch must be (B, k={mu.shape[0]}); got "
                          f"{tuple(mixes.shape)}")
-    with _obs_span("solve_targets_jax") as sp:
+    with _obs_span("repro.targets.solve") as sp:
         if solver == "block":
             targets, xs, _, _ = grin_solve_batch_jax(mu, mixes_np,
                                                      objective=objective,
@@ -475,34 +478,49 @@ def solve_targets_grid_jax(mus, mixes, solver: str = "block",
     `P` ((G, k, l) or (k, l)) overrides the priced power matrix — the
     physical one when `mus` are class-weighted (`physical_power_matrix`).
     """
-    mus = np.asarray(mus, dtype=np.float64)
-    mixes = np.asarray(mixes, dtype=np.int64)
-    if mus.ndim != 3 or mixes.ndim != 2 or mus.shape[1] != mixes.shape[1]:
-        raise ValueError("need mus (G, k, l) and mixes (M, k) with matching "
-                         f"k; got {mus.shape} and {mixes.shape}")
-    G, k, l = mus.shape
-    M = mixes.shape[0]
-    mu_b = np.repeat(mus, M, axis=0)                    # (G*M, k, l)
-    mix_b = np.tile(mixes, (G, 1))                      # (G*M, k)
-    if P is not None and np.ndim(P) == 3:
-        P = np.repeat(np.asarray(P), M, axis=0)         # align with mu_b
-    with _obs_span("solve_targets_grid_jax") as sp:
-        if solver == "block":
-            raw, xs, conv, _ = grin_solve_batch_jax(mu_b, mix_b,
+    with _obs_span("repro.grid.batch"):
+        mus = np.asarray(mus, dtype=np.float64)
+        mixes = np.asarray(mixes, dtype=np.int64)
+        if (mus.ndim != 3 or mixes.ndim != 2
+                or mus.shape[1] != mixes.shape[1]):
+            raise ValueError("need mus (G, k, l) and mixes (M, k) with "
+                             f"matching k; got {mus.shape} and "
+                             f"{mixes.shape}")
+        G, k, l = mus.shape
+        M = mixes.shape[0]
+        mu_b = np.repeat(mus, M, axis=0)                # (G*M, k, l)
+        mix_b = np.tile(mixes, (G, 1))                  # (G*M, k)
+        if P is not None and np.ndim(P) == 3:
+            P = np.repeat(np.asarray(P), M, axis=0)     # align with mu_b
+    moves = None
+    if solver == "block":
+        raw, xs, conv, moves = grin_solve_batch_jax(mu_b, mix_b,
                                                     objective=objective,
                                                     power=power, P=P)
-        elif solver == "single":
-            if objective != "max-x":
-                raise ValueError("energy objectives need solver='block'")
-            raw, xs, conv = _solve_targets_single_grid(
-                jnp.asarray(mu_b, jnp.float32),
-                jnp.asarray(mix_b, jnp.float32))
-        else:
-            raise ValueError(f"unknown solver {solver!r}: block | single")
-        raw, xs, conv = sp.ready((raw, xs, conv))
+    elif solver == "single":
+        if objective != "max-x":
+            raise ValueError("energy objectives need solver='block'")
+        raw, xs, conv = _solve_targets_single_grid(
+            jnp.asarray(mu_b, jnp.float32), jnp.asarray(mix_b, jnp.float32))
+    else:
+        raise ValueError(f"unknown solver {solver!r}: block | single")
+    # lane occupancy, counted while tracing: the loop runs as deep as its
+    # slowest lane
+    counted = moves if tracing_active() else None
+    with _obs_span("repro.grid.fetch") as sp:
+        if counted is not None:
+            # started first, so that it runs beside the results' copies
+            counted.copy_to_host_async()
+        # the wait for the device and the copies back
         conv = np.asarray(conv).reshape(G, M)
-    targets = _repair_targets(np.asarray(raw), mix_b).reshape(G, M, k, l)
-    return targets, np.asarray(xs).reshape(G, M), conv
+        raw = np.asarray(raw)
+        xs = np.asarray(xs).reshape(G, M)
+        if counted is not None:
+            moves = np.asarray(counted)
+            sp.count(lanes=moves.size, moves_max=int(moves.max()),
+                     moves_mean=float(moves.mean()))
+    targets = _repair_targets(raw, mix_b).reshape(G, M, k, l)
+    return targets, xs, conv
 
 
 # ---------------------------------------------------------------------------
@@ -1060,7 +1078,7 @@ class SchedulerCore:
         padded[:m] = types
         valid = np.zeros(cap, dtype=bool)
         valid[:m] = True
-        with _obs_span("route_many") as sp:
+        with _obs_span("repro.route.many") as sp:
             counts, js = sp.ready(_route_many_kernel(
                 jnp.asarray(target, dtype=jnp.int32),
                 jnp.asarray(self._ranks),

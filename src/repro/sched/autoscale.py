@@ -43,6 +43,7 @@ from repro.core.energy import DVFSModel, expected_energy_batch_jax
 from repro.core.grin import grin_block_solve
 from repro.core.slsqp import round_largest_remainder
 from repro.faults.scenario import PoolEvent
+from repro.obs.profile import span as _obs_span
 from repro.sched.api import SchedulerCore, solve_targets_grid_jax
 
 GUARD_W = 1.0e4        # big-M phantom rate; >> any physical service rate
@@ -97,27 +98,30 @@ def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
     `x` (C, M) guard-corrected X_sys, `energy` (C, M) J/task at the solved
     placement under alpha-power-scaled physical power, and `conv` (C, M).
     """
-    nominal_mu = np.asarray(nominal_mu, dtype=np.float64)
-    freq_grid = np.asarray(freq_grid, dtype=np.float64)
-    mixes = np.asarray(mixes, dtype=np.int64)
-    k, l = nominal_mu.shape
-    C = freq_grid.shape[0]
-    M = mixes.shape[0]
-    mus = guarded_candidate_mus(nominal_mu, freq_grid, dvfs)
-    targets, xs, conv = solve_targets_grid_jax(mus, guarded_mixes(mixes, l))
-    n_parked = (freq_grid == 0).sum(axis=1)
-    x = xs - GUARD_W * (n_parked + GUARD_DUMMY)[:, None]
-    real = targets[:, :, :k, :l]
-    # Energy priced in one batched elementwise call: per-candidate scaled
-    # (mu, P) against the (C*M, k, l) placements. Parked columns hold no
-    # tasks, so their zeroed rates/powers contribute nothing.
-    mu_s = dvfs.scale_mu(nominal_mu[None], freq_grid[:, None, :])
-    P_s = dvfs.scale_power(np.asarray(P_nominal)[None],
-                           freq_grid[:, None, :])
-    energy = np.asarray(expected_energy_batch_jax(
-        real.reshape(C * M, k, l),
-        np.repeat(mu_s, M, axis=0),
-        np.repeat(P_s, M, axis=0))).reshape(C, M).astype(np.float64)
+    with _obs_span("repro.price.guard"):
+        nominal_mu = np.asarray(nominal_mu, dtype=np.float64)
+        freq_grid = np.asarray(freq_grid, dtype=np.float64)
+        mixes = np.asarray(mixes, dtype=np.int64)
+        k, l = nominal_mu.shape
+        C = freq_grid.shape[0]
+        M = mixes.shape[0]
+        mus = guarded_candidate_mus(nominal_mu, freq_grid, dvfs)
+        g_mixes = guarded_mixes(mixes, l)
+    targets, xs, conv = solve_targets_grid_jax(mus, g_mixes)
+    with _obs_span("repro.price.energy"):
+        n_parked = (freq_grid == 0).sum(axis=1)
+        x = xs - GUARD_W * (n_parked + GUARD_DUMMY)[:, None]
+        real = targets[:, :, :k, :l]
+        # Energy priced in one batched elementwise call: per-candidate
+        # scaled (mu, P) against the (C*M, k, l) placements. Parked columns
+        # hold no tasks, so their zeroed rates/powers contribute nothing.
+        mu_s = dvfs.scale_mu(nominal_mu[None], freq_grid[:, None, :])
+        P_s = dvfs.scale_power(np.asarray(P_nominal)[None],
+                               freq_grid[:, None, :])
+        energy = np.asarray(expected_energy_batch_jax(
+            real.reshape(C * M, k, l),
+            np.repeat(mu_s, M, axis=0),
+            np.repeat(P_s, M, axis=0))).reshape(C, M).astype(np.float64)
     return {"targets": real, "x": np.maximum(x, 0.0), "energy": energy,
             "conv": conv}
 

@@ -225,9 +225,63 @@ def test_profile_block_restores_state_and_captures_library_spans():
         targets, _ = solve_targets_jax(MU, np.array([[4, 4]]))
     assert not get_profiler().enabled
     names = {s.name for s in prof.spans}
-    assert "solve_targets_jax" in names
+    assert "repro.targets.solve" in names
     assert np.asarray(targets).shape == (1,) + MU.shape
     enable_profiling(False)
+
+
+def test_span_untraced_with_profiler_off_records_nothing():
+    from repro.obs import span, tracing_active
+    get_profiler().clear()
+    assert not tracing_active() and not get_profiler().enabled
+    sentinel = object()
+    with span("repro.test.off") as sp:
+        assert sp.count(rows=3) is None
+        assert sp.ready(sentinel) is sentinel
+    with Profiler(enabled=False).span("repro.test.off") as sp:
+        sp.count(rows=3)
+    assert get_profiler().spans == []
+
+
+# the re-solve path's host phases, in the order one request runs them
+PRICE_SPANS = ["repro.price.guard", "repro.grid.batch", "repro.grin.prep",
+               "repro.grin.dispatch", "repro.grid.fetch", "repro.grid.repair",
+               "repro.price.energy"]
+
+
+def test_price_grid_spans_land_in_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.core.energy import DVFSModel
+    from repro.sched.autoscale import price_frequency_grid
+    rng = np.random.default_rng(7)
+    mu = rng.uniform(2.0, 20.0, size=(2, 3))
+    freqs = np.array([[1.0, 1.0, 1.0], [0.0, 1.0, 1.0], [1.25, 1.0, 0.5]])
+    mixes = rng.multinomial(40, [0.6, 0.4], size=4)
+    args = (mu, mu, freqs, mixes, DVFSModel())
+    price_frequency_grid(*args)                    # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with profile_block("t") as prof:
+            price_frequency_grid(*args)
+    finally:
+        jax.profiler.stop_trace()
+    # the enabled Profiler keeps the same spans on its own clock
+    assert [s.name for s in prof.spans] == PRICE_SPANS
+    prof.clear()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    evs = sorted(((ev.start_ns, ev.name, dict(ev.stats))
+                  for plane in ProfileData.from_file(path[0]).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("repro.")), key=lambda t: t[0])
+    assert [name for _, name, _ in evs] == PRICE_SPANS
+    stats = {name: st for _, name, st in evs}
+    assert stats["repro.grid.repair"]["rows"] >= 0
+    fetch = stats["repro.grid.fetch"]
+    assert fetch["lanes"] == len(freqs) * len(mixes)
+    assert 0 <= fetch["moves_mean"] <= fetch["moves_max"]
 
 
 # ----------------------------------------------- telemetry accumulator
