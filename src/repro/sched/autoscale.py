@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from repro.core.affinity import PowerModel, PROPORTIONAL_POWER
@@ -88,11 +89,22 @@ def guarded_mixes(mixes: np.ndarray, l: int) -> np.ndarray:
         [mixes, np.ones((mixes.shape[0], l), dtype=np.int64)], axis=1)
 
 
+@jax.jit
+def _energy_grid_jax(packed):
+    """eq. 19 energy (C, M) of a whole candidate x mix grid in one compiled
+    call. `packed` is (C, M + 2, k, l) float32: each candidate's M
+    placements, then its DVFS-scaled mu and P, which broadcast over the mix
+    axis here, so the host ships them once per candidate in one copy."""
+    return expected_energy_batch_jax(packed[:, :-2], packed[:, -2:-1],
+                                     packed[:, -1:])
+
+
 def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
                          freq_grid: np.ndarray, mixes: np.ndarray,
                          dvfs: DVFSModel):
     """Price every candidate frequency vector against every mix in ONE
-    batched device solve (the decision-epoch hot path).
+    batched device solve (the decision-epoch hot path), then its energy in
+    one compiled call.
 
     Returns dict with `targets` (C, M, k, l) real-slice placements,
     `x` (C, M) guard-corrected X_sys, `energy` (C, M) J/task at the solved
@@ -103,8 +115,6 @@ def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
         freq_grid = np.asarray(freq_grid, dtype=np.float64)
         mixes = np.asarray(mixes, dtype=np.int64)
         k, l = nominal_mu.shape
-        C = freq_grid.shape[0]
-        M = mixes.shape[0]
         mus = guarded_candidate_mus(nominal_mu, freq_grid, dvfs)
         g_mixes = guarded_mixes(mixes, l)
     targets, xs, conv = solve_targets_grid_jax(mus, g_mixes)
@@ -112,16 +122,16 @@ def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
         n_parked = (freq_grid == 0).sum(axis=1)
         x = xs - GUARD_W * (n_parked + GUARD_DUMMY)[:, None]
         real = targets[:, :, :k, :l]
-        # Energy priced in one batched elementwise call: per-candidate
-        # scaled (mu, P) against the (C*M, k, l) placements. Parked columns
-        # hold no tasks, so their zeroed rates/powers contribute nothing.
+        # Energy priced in one compiled call: the placements (exact in
+        # float32) and each candidate's scaled (mu, P), scaled in float64
+        # here, shipped as one float32 array. Parked columns hold no tasks,
+        # so their zeroed rates/powers contribute nothing.
         mu_s = dvfs.scale_mu(nominal_mu[None], freq_grid[:, None, :])
         P_s = dvfs.scale_power(np.asarray(P_nominal)[None],
                                freq_grid[:, None, :])
-        energy = np.asarray(expected_energy_batch_jax(
-            real.reshape(C * M, k, l),
-            np.repeat(mu_s, M, axis=0),
-            np.repeat(P_s, M, axis=0))).reshape(C, M).astype(np.float64)
+        packed = np.concatenate([real, mu_s[:, None], P_s[:, None]],
+                                axis=1).astype(np.float32)
+        energy = np.asarray(_energy_grid_jax(packed)).astype(np.float64)
     return {"targets": real, "x": np.maximum(x, 0.0), "energy": energy,
             "conv": conv}
 

@@ -132,6 +132,69 @@ def test_guard_encoding_matches_host_submatrix_solves():
         assert abs(priced["energy"][c, 0] - e_ref) < 2e-2 * e_ref
 
 
+def _parked_grid(parked_sets, l, levels):
+    """One candidate per parked set: pool j of candidate c runs at
+    levels[(c + j) % len(levels)] unless parked (f = 0)."""
+    C = len(parked_sets)
+    idx = (np.arange(C)[:, None] + np.arange(l)) % len(levels)
+    grid = np.asarray(levels, dtype=np.float64)[idx]
+    for c, parked in enumerate(parked_sets):
+        grid[c, parked] = 0.0
+    return grid
+
+
+def test_compiled_energy_matches_eager_and_host_pricing():
+    """The grid's one compiled energy call prices every (candidate, mix)
+    point as the eager `expected_energy_batch_jax` does on the same
+    placements with the per-candidate rates repeated over the mixes, and as
+    the float64 host formula does; parked columns add nothing and an empty
+    mix (zero throughput) prices at inf."""
+    from repro.core.energy import expected_energy_batch_jax
+    rng = np.random.default_rng(7)
+    mu = rng.uniform(2.0, 30.0, size=(3, 4))
+    mu[2] = [1.0, 1.2, 0.9, 1.1]
+    k, l = mu.shape
+    P = PROPORTIONAL_POWER.power_matrix(mu)
+    grid = _parked_grid([[], [2], [1, 3], [0, 2, 3]], l,
+                        levels=(0.5, 1.25, 0.75, 1.0))
+    mixes = np.array([[12, 9, 7], [1, 0, 5], [30, 2, 2], [0, 0, 0]])
+    C, M = len(grid), len(mixes)
+    priced = price_frequency_grid(mu, P, grid, mixes, DVFS)
+    energy = priced["energy"]
+    assert energy.shape == (C, M) and energy.dtype == np.float64
+    mu_s = DVFS.scale_mu(mu[None], grid[:, None, :])
+    P_s = DVFS.scale_power(P[None], grid[:, None, :])
+    eager = np.asarray(expected_energy_batch_jax(
+        priced["targets"].reshape(C * M, k, l), np.repeat(mu_s, M, axis=0),
+        np.repeat(P_s, M, axis=0))).reshape(C, M)
+    assert np.isinf(energy[:, -1]).all() and np.isinf(eager[:, -1]).all()
+    np.testing.assert_allclose(energy[:, :-1], eager[:, :-1], rtol=1e-6)
+    for c in range(C):
+        for m in range(M - 1):
+            tg = priced["targets"][c, m]
+            assert tg[:, grid[c] == 0].sum() == 0
+            e_host = _energy_per_task(tg, mu_s[c], P_s[c])
+            assert abs(energy[c, m] - e_host) <= 1e-5 * e_host, (c, m)
+
+
+def test_energy_pricing_compiles_once_per_grid_shape():
+    """After one warm call, fresh mixes of the same (C, M, k, l) reuse the
+    pricing program: no compile lands in a served window."""
+    from repro.sched.autoscale import _energy_grid_jax
+    rng = np.random.default_rng(3)
+    mu = rng.uniform(2.0, 30.0, size=(3, 4))
+    P = PROPORTIONAL_POWER.power_matrix(mu)
+    grid = _parked_grid([[], [1], [0, 3]], 4, levels=(0.75, 1.25))
+    _energy_grid_jax.clear_cache()
+    price_frequency_grid(mu, P, grid, rng.integers(1, 20, size=(5, 3)), DVFS)
+    assert _energy_grid_jax._cache_size() == 1
+    for _ in range(3):
+        priced = price_frequency_grid(mu, P, grid,
+                                      rng.integers(1, 20, size=(5, 3)), DVFS)
+        assert np.isfinite(priced["energy"]).all()
+    assert _energy_grid_jax._cache_size() == 1
+
+
 def test_guarded_candidate_mus_shapes_and_guards():
     mu = np.ones((2, 3))
     grid = np.array([[1.0, 0.0, 0.5]])
