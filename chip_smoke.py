@@ -78,7 +78,7 @@ def solver_phase():
     from repro.kernels.grin_moves import OBJ_X
     from repro.obs.meta import kernel_mode
     from repro.sched import SchedulerCore, solve_targets_grid_jax
-    from repro.sched.api import _repair_targets, solve_targets_jax
+    from repro.sched.api import round_placements_jax, solve_targets_jax
     from repro.sched.autoscale import AutoscaleGovernor
 
     check(kernel_mode() == "pallas-compiled", f"kernel_mode={kernel_mode()}")
@@ -107,7 +107,7 @@ def solver_phase():
     x_k = xs.reshape(-1)
     x_x = np.asarray(x_x)
     rel = np.abs(x_k - x_x) / np.abs(x_x)
-    n_diff = int((_repair_targets(np.asarray(n_x), mix_b.astype(np.int64))
+    n_diff = int((np.asarray(round_placements_jax(n_x, mix_b)[0])
                   != targets.reshape(n_x.shape)).any(axis=(1, 2)).sum())
     log(f"kernel vs XLA path: placements differ on {n_diff}/{G * M} points, "
         f"max rel X_sys diff {rel.max():.3e} (tol {_TOL32_BLOCK:g}); "

@@ -337,11 +337,20 @@ def grin_x_sys_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("n_sizes", "max_moves",
-                                             "kernel", "objective"))
-def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, kernel, objective):
+                                             "kernel", "objective", "grid"))
+def _grin_block_core(mus, mixes, Ps, n_sizes, max_moves, kernel, objective,
+                     grid=False):
     from repro.core.energy import edp_batch_jax, expected_energy_batch_jax
     from repro.kernels.grin_moves import (OBJ_E_GUARD, OBJ_EDP, OBJ_X,
                                           OBJ_XE, block_move_scores)
+    if grid:
+        # (G, k, l) matrices x (M, k) mixes: the G*M instances, matrix-major,
+        # are built here on the device, so only the factors cross the link
+        G, M = mus.shape[0], mixes.shape[0]
+        mus, Ps = (jnp.broadcast_to(a[:, None], (G, M) + a.shape[1:])
+                   .reshape((G * M,) + a.shape[1:]) for a in (mus, Ps))
+        mixes = jnp.broadcast_to(mixes[None], (G,) + mixes.shape).reshape(
+            G * M, mixes.shape[1])
     B, k, l = mus.shape
     # Largest size first: argmax ties prefer the biggest improving block.
     sizes = jnp.float32(2) ** jnp.arange(n_sizes - 1, -1, -1)
@@ -409,10 +418,21 @@ def _objective_id(objective: str) -> int:
     return ids[objective]
 
 
+def _device_f32(x):
+    """`x` as a float32 device array with no program run for the cast: a
+    host array is cast here and copied as it is (`jnp.asarray` of a float64
+    array would copy it and convert it on the device, one program a call);
+    a device array is converted where it lives (a no-op when float32)."""
+    if isinstance(x, jax.Array):
+        return x.astype(jnp.float32)
+    return jnp.asarray(np.asarray(x, dtype=np.float32))
+
+
 def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
                          max_moves: int | None = None,
                          use_kernel: bool | None = None,
-                         objective: str = "max-x", power=None, P=None):
+                         objective: str = "max-x", power=None, P=None,
+                         grid: bool = False):
     """Block-move GrIn over a batch of instances, in one device call.
 
     mu: (k, l) shared or (B, k, l) per-instance affinities; n_tasks_batch:
@@ -424,6 +444,12 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
     False) signals a degenerate instance rather than a small budget.
     `use_kernel` picks the Pallas scoring kernel (None: compiled on TPU,
     jnp elsewhere; see `repro.kernels.grin_moves.kernel_mode`).
+
+    `grid=True` solves every matrix of `mu` (G, k, l) against every mix of
+    `n_tasks_batch` (M, k): B = G*M instances, matrix-major (instance
+    g*M + m), built on the device from the two factors, so no (G*M, ...)
+    array is made on the host or crosses the link. `P` is then (k, l) or
+    (G, k, l).
 
     `objective` selects what moves are ranked by (paper Sec. 3.4 /
     arXiv:1607.07763 multi-objective framing), with the power matrix
@@ -444,22 +470,27 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
     from repro.kernels.grin_moves import OBJ_X, kernel_mode
     from repro.obs.profile import span as _obs_span
     with _obs_span("repro.grin.prep"):
-        mixes = jnp.asarray(n_tasks_batch, dtype=jnp.float32)
-        mus = jnp.asarray(mu, dtype=jnp.float32)
+        mixes = _device_f32(n_tasks_batch)
+        mus = _device_f32(mu)
         if mixes.ndim != 2:
             raise ValueError(f"n_tasks_batch must be (B, k); got "
                              f"{mixes.shape}")
         B, k = mixes.shape
-        if mus.ndim == 2:
-            mus = jnp.broadcast_to(mus, (B,) + mus.shape)
-        if mus.ndim != 3 or mus.shape[:2] != (B, k):
-            raise ValueError(f"mu must be (k={k}, l) or (B={B}, k={k}, l); "
-                             f"got {tuple(jnp.shape(mu))}")
+        if grid:
+            if mus.ndim != 3 or mus.shape[1] != k:
+                raise ValueError(f"mu must be (G, k={k}, l) for a grid; got "
+                                 f"{tuple(jnp.shape(mu))}")
+        else:
+            if mus.ndim == 2:
+                mus = jnp.broadcast_to(mus, (B,) + mus.shape)
+            if mus.ndim != 3 or mus.shape[:2] != (B, k):
+                raise ValueError(f"mu must be (k={k}, l) or (B={B}, k={k}, "
+                                 f"l); got {tuple(jnp.shape(mu))}")
         obj = _objective_id(objective)
         if obj == OBJ_X:
             Ps = mus            # unused by the throughput objective
         elif P is not None:
-            Ps = jnp.broadcast_to(jnp.asarray(P, jnp.float32), mus.shape)
+            Ps = jnp.broadcast_to(_device_f32(P), mus.shape)
         else:
             from repro.core.affinity import PROPORTIONAL_POWER
             from repro.core.energy import power_matrix_jax
@@ -470,4 +501,4 @@ def grin_solve_batch_jax(mu, n_tasks_batch, *, n_sizes: int | None = None,
         kernel = kernel_mode(use_kernel)
     with _obs_span("repro.grin.dispatch"):
         return _grin_block_core(mus, mixes, Ps, int(n_sizes), max_moves,
-                                kernel, obj)
+                                kernel, obj, grid)

@@ -44,6 +44,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
+from typing import NamedTuple
 
 import numpy as np
 
@@ -56,12 +57,12 @@ from repro.core.affinity import PROPORTIONAL_POWER, PowerModel
 from repro.core.cab import cab_target_state
 from repro.core.energy import expected_energy_batch_jax
 from repro.core.exhaustive import exhaustive_solve
-from repro.core.grin import grin_solve, grin_solve_batch_jax, grin_solve_jax
+from repro.core.grin import (_ladder, grin_solve, grin_solve_batch_jax,
+                              grin_solve_jax)
 from repro.core.grin_energy import grin_energy_solve
 from repro.core.grin_plus import grin_multistart_solve
 from repro.core.slsqp import round_largest_remainder, slsqp_solve
 from repro.core.throughput import (state_from_pair,
-                                   system_throughput_batch_jax,
                                    system_throughput_jax, throughput_map_2x2)
 from repro.train.fault_tolerance import StragglerTracker
 
@@ -374,37 +375,171 @@ class JoinShortestQueuePolicy(Policy):
 # Batched on-device target solving
 # ---------------------------------------------------------------------------
 
-@jax.jit
-def _solve_targets_single_jax(mu: jnp.ndarray, mixes: jnp.ndarray):
-    targets = jax.vmap(lambda nt: grin_solve_jax(mu, nt))(mixes)
-    xs = system_throughput_batch_jax(targets, mu)
-    return targets, xs
+# A grid solve crosses the link once each way. The factors go in, cast to
+# float32 on the host: rate matrices (G, k, l) and mixes (M, k). The solver
+# builds the G*M instances on the device, a compiled step enqueued behind it
+# rounds the placements and packs every per-instance result into one
+# float32 array, and one blocking copy brings that array back. Only an
+# instance whose rounded rows miss its mix costs a second copy, of its raw
+# rows, re-rounded on the host; none does while placements stay exact
+# integers in float32.
+
+_F32_EXACT = 2 ** 24     # float32 holds every integer below this exactly
 
 
 @jax.jit
 def _solve_targets_single_grid(mus: jnp.ndarray, mixes: jnp.ndarray):
-    targets, conv, _ = jax.vmap(
+    """Single-move solves of every matrix of `mus` (G, k, l) against every
+    mix of `mixes` (M, k), matrix-major, in one device call."""
+    G, M = mus.shape[0], mixes.shape[0]
+    mus = jnp.repeat(mus, M, axis=0)
+    mixes = jnp.tile(mixes, (G, 1))
+    targets, conv, moves = jax.vmap(
         lambda m, nt: grin_solve_jax(m, nt, return_info=True))(mus, mixes)
     xs = jax.vmap(system_throughput_jax)(targets, mus)
-    return targets, xs, conv
+    return targets, xs, conv, moves
 
 
-def _repair_targets(raw: np.ndarray, mixes: np.ndarray) -> np.ndarray:
-    """Round float placements to integers with EXACT row sums.
+def round_placements_jax(raw, mixes):
+    """Round a grid's float placements on the device, as `np.round` does
+    (half to even), and flag the instances whose rounded rows miss their mix.
 
-    The device solvers accumulate placements in float32, so a plain
-    `.round()` can drift a row off its task count on large mixes; rows that
-    drift are re-rounded by largest remainder (the same repair SLSQP uses).
+    raw: (B, k, l) float32, instance b solved for mix b % M of `mixes`
+    (M, k), as a matrix-major grid lays them out. Each row's sum is compared
+    with its mix exactly, in int32: placements and mixes are exact integers
+    in float32 below 2**24, which `start_targets_grid` checks. Returns
+    (counts (B, k, l) int32 clamped at 0, drifted (B,) bool), the flags
+    taken before the clamp; `_repair_targets` re-rounds the flagged ones.
+    """
+    counts = jnp.round(raw).astype(jnp.int32)
+    want = jnp.tile(mixes.astype(jnp.int32), (raw.shape[0] // mixes.shape[0],
+                                              1))
+    drifted = (counts.sum(axis=-1) != want).any(axis=-1)
+    return jnp.maximum(counts, 0), drifted
+
+
+def pack_grid_rows(counts, drifted, xs, conv, moves, *extra):
+    """A grid's per-instance results as one float32 array, so that one
+    device-to-host copy brings them all: (B, 4 + len(extra) + ka * la),
+    columns x_sys, converged, drifted, moves, the `extra` (B,) columns, then
+    the (B, ka, la) placement `counts`. Counts and moves are integers below
+    2**24, exact in float32."""
+    head = jnp.stack([c.astype(jnp.float32)
+                      for c in (xs, conv, drifted, moves, *extra)], axis=1)
+    return jnp.concatenate(
+        [head, counts.reshape(counts.shape[0], -1).astype(jnp.float32)],
+        axis=1)
+
+
+@jax.jit
+def _grid_rows_jax(raw, xs, conv, moves, mixes):
+    """The rounding and packing step of `solve_targets_grid_jax`."""
+    counts, drifted = round_placements_jax(raw, mixes)
+    return pack_grid_rows(counts, drifted, xs, conv, moves)
+
+
+def _repair_targets(targets: np.ndarray, drifted: np.ndarray, raw,
+                    mixes: np.ndarray) -> np.ndarray:
+    """Exact row sums for the instances the device rounding flagged.
+
+    The placements were rounded on the device (`round_placements_jax`), and
+    `targets` (B, ka, la) int64 holds them on the host: whole (k, l)
+    placements or a leading slice of each. Only the `drifted` instances are
+    repaired here: their raw float rows alone are read from `raw` (B, k, l),
+    a second device-to-host copy, re-rounded whole by largest remainder (the
+    same repair SLSQP uses) and clamped at 0, and their slice is written
+    back into `targets`. mixes: (M, k) int, instance b's mix is b % M.
+    Returns the indices of the repaired instances.
     """
     with _obs_span("repro.grid.repair") as sp:
-        raw = np.asarray(raw, dtype=np.float64)
+        idx = np.flatnonzero(drifted)
+        if idx.size:
+            rows = np.asarray(raw[idx], dtype=np.float64)
+            ka, la = targets.shape[1:]
+            for row, b in zip(rows, idx):
+                targets[b] = round_largest_remainder(
+                    row, mixes[b % len(mixes)])[:ka, :la]
+        sp.count(rows=idx.size)
+        return idx
+
+
+class GridResult(NamedTuple):
+    targets: np.ndarray     # (G, M, ka, la) int64, exact row sums
+    x_sys: np.ndarray       # (G, M) float32, of the solver's placements
+    converged: np.ndarray   # (G, M) bool
+    extra: np.ndarray       # (G, M, n) float32: a pricing step's columns
+    repaired: np.ndarray    # flat indices g*M + m re-rounded on the host
+
+
+@dataclasses.dataclass(frozen=True)
+class GridSolve:
+    """A (rate matrix x mix) grid solve enqueued on the device, with nothing
+    waited on: `start_targets_grid` makes one. A compiled step of its
+    results goes behind it, rounding with `round_placements_jax` and
+    packing with `pack_grid_rows` (`_grid_rows_jax`, or a caller's own that
+    prices the placements too), and `finish` brings that step's array back
+    in one copy."""
+    raw: jax.Array          # (G*M, k, l) float32 placements, matrix-major
+    xs: jax.Array           # (G*M,) X_sys of the raw placements
+    conv: jax.Array         # (G*M,) bool
+    moves: jax.Array        # (G*M,) int32 block moves made
+    mixes: jax.Array        # (M, k) float32, on the device
+    host_mixes: np.ndarray  # (M, k) int64
+    G: int
+
+    def finish(self, rows, shape: tuple) -> GridResult:
+        """One blocking copy of `rows` (a `pack_grid_rows` array whose
+        placements are (ka, la) = `shape`), then the host repair of the
+        flagged instances (`_repair_targets`)."""
+        G, M = self.G, len(self.host_mixes)
+        with _obs_span("repro.grid.fetch") as sp:
+            # the request's one blocking device-to-host copy
+            rows = jax.device_get(rows)
+            if tracing_active():
+                # lane occupancy: the loop runs as deep as its slowest lane
+                moves = rows[:, 3]
+                sp.count(lanes=moves.size, moves_max=int(moves.max()),
+                         moves_mean=float(moves.mean()))
+        head = rows.shape[1] - shape[0] * shape[1]
+        targets = rows[:, head:].astype(np.int64).reshape((G * M,) + shape)
+        repaired = _repair_targets(targets, rows[:, 2] != 0, self.raw,
+                                   self.host_mixes)
+        return GridResult(targets.reshape((G, M) + shape),
+                          rows[:, 0].reshape(G, M),
+                          rows[:, 1].reshape(G, M) != 0,
+                          rows[:, 4:head].reshape(G, M, -1), repaired)
+
+
+def start_targets_grid(mus, mixes, solver: str = "block",
+                       objective: str = "max-x",
+                       power: PowerModel | None = None, P=None) -> GridSolve:
+    """Enqueue a whole (mu x mix) target grid's solve on the device and
+    return without waiting; arguments as `solve_targets_grid_jax`. The
+    rate matrices and mixes are cast to float32 here and copied once each;
+    the solver builds the G*M instances from them on the device."""
+    with _obs_span("repro.grid.batch"):
         mixes = np.asarray(mixes, dtype=np.int64)
-        out = raw.round().astype(np.int64)
-        drifted = np.flatnonzero((out.sum(axis=-1) != mixes).any(axis=-1))
-        for b in drifted:
-            out[b] = round_largest_remainder(raw[b], mixes[b])
-        sp.count(rows=drifted.size)
-        return np.maximum(out, 0)
+        if (np.ndim(mus) != 3 or mixes.ndim != 2
+                or np.shape(mus)[1] != mixes.shape[1]):
+            raise ValueError("need mus (G, k, l) and mixes (M, k) with "
+                             f"matching k; got {np.shape(mus)} and "
+                             f"{mixes.shape}")
+        if mixes.sum(axis=1).max() >= _F32_EXACT:
+            raise ValueError("a mix must hold fewer than 2**24 tasks, so "
+                             "that float32 placements stay exact integers")
+        if solver not in ("block", "single"):
+            raise ValueError(f"unknown solver {solver!r}: block | single")
+        if solver == "single" and objective != "max-x":
+            raise ValueError("energy objectives need solver='block'")
+        mus_d = jnp.asarray(np.asarray(mus, dtype=np.float32))
+        mixes_d = jnp.asarray(mixes.astype(np.float32))
+    if solver == "block":
+        raw, xs, conv, moves = grin_solve_batch_jax(
+            mus_d, mixes_d, grid=True, objective=objective, power=power,
+            P=P, n_sizes=len(_ladder(int(mixes.sum(axis=1).max()))))
+    else:
+        raw, xs, conv, moves = _solve_targets_single_grid(mus_d, mixes_d)
+    return GridSolve(raw, xs, conv, moves, mixes_d, mixes, mus_d.shape[0])
 
 
 def physical_power_matrix(policy: Policy, mus: np.ndarray):
@@ -427,9 +562,10 @@ def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
     """Batched GrIn re-solve over many type mixes, vectorized on device.
 
     Returns (targets (B, k, l) int64, x_sys (B,) float), with row sums
-    repaired to match the mixes exactly. Used for policy sweeps and
-    piecewise-closed target pre-warming where looping the NumPy solver in
-    Python would dominate.
+    exactly matching the mixes. Used for policy sweeps and piecewise-closed
+    target pre-warming where looping the NumPy solver in Python would
+    dominate. One matrix against B mixes is a grid of one row, solved,
+    rounded and fetched as `solve_targets_grid_jax` does.
 
     `solver="block"` (default) is the block-move GrIn — O(log N)-ish device
     steps per solve; `solver="single"` keeps the one-move-per-step variant
@@ -437,90 +573,45 @@ def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
     maxima of the same objective and may land in a different (same-quality-
     class) basin than the host sweep solver. `objective`/`power` switch the
     block solver to the energy objectives (GrIn-E/GrIn-EDP); the single-move
-    solver is throughput-only. `P` overrides the power matrix the energy
-    objectives price moves against — callers solving under a class-weighted
-    `device_mu` pass the PHYSICAL matrix here (see `physical_power_matrix`)
-    so watts are never scaled by weights.
+    solver is throughput-only. `P` overrides the (k, l) power matrix the
+    energy objectives price moves against — callers solving under a
+    class-weighted `device_mu` pass the PHYSICAL matrix here (see
+    `physical_power_matrix`) so watts are never scaled by weights.
     """
-    mu = jnp.asarray(mu, dtype=jnp.float32)
-    mixes_np = np.asarray(n_tasks_batch)
-    mixes = jnp.asarray(mixes_np, dtype=jnp.float32)
+    mu = np.asarray(mu)
+    mixes = np.asarray(n_tasks_batch)
     if mixes.ndim != 2 or mixes.shape[1] != mu.shape[0]:
         raise ValueError(f"n_tasks_batch must be (B, k={mu.shape[0]}); got "
                          f"{tuple(mixes.shape)}")
-    with _obs_span("repro.targets.solve") as sp:
-        if solver == "block":
-            targets, xs, _, _ = grin_solve_batch_jax(mu, mixes_np,
-                                                     objective=objective,
-                                                     power=power, P=P)
-        elif solver == "single":
-            if objective != "max-x":
-                raise ValueError("energy objectives need solver='block'")
-            targets, xs = _solve_targets_single_jax(mu, mixes)
-        else:
-            raise ValueError(f"unknown solver {solver!r}: block | single")
-        targets, xs = sp.ready((targets, xs))
-    return _repair_targets(np.asarray(targets), mixes_np), np.asarray(xs)
+    with _obs_span("repro.targets.solve"):
+        targets, xs, _ = solve_targets_grid_jax(mu[None], mixes, solver,
+                                                objective, power, P)
+    return targets[0], xs[0]
 
 
 def solve_targets_grid_jax(mus, mixes, solver: str = "block",
                            objective: str = "max-x",
                            power: PowerModel | None = None, P=None):
-    """Whole (mu x mix) target grid in one device call.
+    """Whole (mu x mix) target grid in one device call and one fetch.
 
     mus: (G, k, l) affinity matrices; mixes: (M, k) type mixes. Returns
     (targets (G, M, k, l) int64, x_sys (G, M), converged (G, M) bool). The
-    grid is flattened to a (G*M,) batch for `grin_solve_batch_jax`, so the
-    whole grid costs one compiled while-loop whose depth is the slowest
-    instance's block-move count. This is what makes thousand-point elastic /
-    energy what-if sweeps (mu batching) cheap enough to run interactively.
-    `objective`/`power` switch the block solver to the energy objectives;
-    `P` ((G, k, l) or (k, l)) overrides the priced power matrix — the
-    physical one when `mus` are class-weighted (`physical_power_matrix`).
+    solver builds the (G*M,) batch on the device (`grin_solve_batch_jax`
+    with `grid=True`), so the whole grid costs one compiled while-loop whose
+    depth is the slowest instance's block-move count. A compiled step behind
+    it rounds the placements (`round_placements_jax`) and packs the results,
+    which come back in one copy; only instances whose rounded rows miss
+    their mix are re-rounded on the host (`_repair_targets`). This is what
+    makes thousand-point elastic / energy what-if sweeps (mu batching)
+    cheap enough to run interactively. `objective`/`power` switch the block
+    solver to the energy objectives; `P` ((G, k, l) or (k, l)) overrides
+    the priced power matrix — the physical one when `mus` are
+    class-weighted (`physical_power_matrix`).
     """
-    with _obs_span("repro.grid.batch"):
-        mus = np.asarray(mus, dtype=np.float64)
-        mixes = np.asarray(mixes, dtype=np.int64)
-        if (mus.ndim != 3 or mixes.ndim != 2
-                or mus.shape[1] != mixes.shape[1]):
-            raise ValueError("need mus (G, k, l) and mixes (M, k) with "
-                             f"matching k; got {mus.shape} and "
-                             f"{mixes.shape}")
-        G, k, l = mus.shape
-        M = mixes.shape[0]
-        mu_b = np.repeat(mus, M, axis=0)                # (G*M, k, l)
-        mix_b = np.tile(mixes, (G, 1))                  # (G*M, k)
-        if P is not None and np.ndim(P) == 3:
-            P = np.repeat(np.asarray(P), M, axis=0)     # align with mu_b
-    moves = None
-    if solver == "block":
-        raw, xs, conv, moves = grin_solve_batch_jax(mu_b, mix_b,
-                                                    objective=objective,
-                                                    power=power, P=P)
-    elif solver == "single":
-        if objective != "max-x":
-            raise ValueError("energy objectives need solver='block'")
-        raw, xs, conv = _solve_targets_single_grid(
-            jnp.asarray(mu_b, jnp.float32), jnp.asarray(mix_b, jnp.float32))
-    else:
-        raise ValueError(f"unknown solver {solver!r}: block | single")
-    # lane occupancy, counted while tracing: the loop runs as deep as its
-    # slowest lane
-    counted = moves if tracing_active() else None
-    with _obs_span("repro.grid.fetch") as sp:
-        if counted is not None:
-            # started first, so that it runs beside the results' copies
-            counted.copy_to_host_async()
-        # the wait for the device and the copies back
-        conv = np.asarray(conv).reshape(G, M)
-        raw = np.asarray(raw)
-        xs = np.asarray(xs).reshape(G, M)
-        if counted is not None:
-            moves = np.asarray(counted)
-            sp.count(lanes=moves.size, moves_max=int(moves.max()),
-                     moves_mean=float(moves.mean()))
-    targets = _repair_targets(raw, mix_b).reshape(G, M, k, l)
-    return targets, xs, conv
+    run = start_targets_grid(mus, mixes, solver, objective, power, P)
+    res = run.finish(_grid_rows_jax(run.raw, run.xs, run.conv, run.moves,
+                                    run.mixes), run.raw.shape[1:])
+    return res.targets, res.x_sys, res.converged
 
 
 # ---------------------------------------------------------------------------

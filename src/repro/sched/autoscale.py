@@ -4,10 +4,12 @@ The PR 3/4 fabric prices pool-loss/add and energy what-ifs but nothing
 consumed them as a controller. This module closes the loop: a governor
 watches load / utilization / straggler EWMA signals (the PR 6
 `AdmissionController` observation pattern), prices every candidate
-(pool x frequency) action in ONE batched `solve_targets_grid_jax` call
-per decision epoch, and issues `pool_lost` / `pool_added` /
-`set_frequencies` actions under an energy or power-cap budget
-(alpha-power DVFS: mu ∝ f, P ∝ f^alpha — `repro.core.energy.DVFSModel`).
+(pool x frequency) action in ONE batched device solve per decision epoch
+(`price_frequency_grid`: one transfer in, the solve, the rounding and the
+energy pricing enqueued back to back, one fetch), and issues `pool_lost` /
+`pool_added` / `set_frequencies` actions under an energy or power-cap
+budget (alpha-power DVFS: mu ∝ f, P ∝ f^alpha —
+`repro.core.energy.DVFSModel`).
 
 Parked pools in one batched solve — the big-M phantom guard
 ---------------------------------------------------------------------
@@ -37,15 +39,18 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.affinity import PowerModel, PROPORTIONAL_POWER
 from repro.core.energy import DVFSModel, expected_energy_batch_jax
 from repro.core.grin import grin_block_solve
 from repro.core.slsqp import round_largest_remainder
+from repro.core.throughput import system_throughput
 from repro.faults.scenario import PoolEvent
 from repro.obs.profile import span as _obs_span
-from repro.sched.api import SchedulerCore, solve_targets_grid_jax
+from repro.sched.api import (SchedulerCore, pack_grid_rows,
+                             round_placements_jax, start_targets_grid)
 
 GUARD_W = 1.0e4        # big-M phantom rate; >> any physical service rate
 GUARD_DUMMY = 0.99     # dummy-slot discount: guards strictly prefer their pool
@@ -89,14 +94,31 @@ def guarded_mixes(mixes: np.ndarray, l: int) -> np.ndarray:
         [mixes, np.ones((mixes.shape[0], l), dtype=np.int64)], axis=1)
 
 
+def _energy_per_task(N: np.ndarray, mu: np.ndarray, P: np.ndarray) -> float:
+    """eq. 19 in float64 with an explicit (DVFS-scaled) power matrix."""
+    N = np.asarray(N, dtype=np.float64)
+    x = system_throughput(N, mu)
+    col = N.sum(axis=0)
+    W_cols = np.where(col > 0, (N * P).sum(axis=0) / np.maximum(col, 1e-300),
+                      0.0)
+    return float(W_cols.sum() / x) if x > 0 else np.inf
+
+
 @jax.jit
-def _energy_grid_jax(packed):
-    """eq. 19 energy (C, M) of a whole candidate x mix grid in one compiled
-    call. `packed` is (C, M + 2, k, l) float32: each candidate's M
-    placements, then its DVFS-scaled mu and P, which broadcast over the mix
-    axis here, so the host ships them once per candidate in one copy."""
-    return expected_energy_batch_jax(packed[:, :-2], packed[:, -2:-1],
-                                     packed[:, -1:])
+def _price_grid_jax(raw, xs, conv, moves, mixes, rates):
+    """The governor's one compiled pricing step, enqueued behind the grid
+    solve: the placements rounded on the device (`round_placements_jax`),
+    eq. 19 energy of each real slice (k, l) under its candidate's
+    DVFS-scaled mu and P (`rates` (C, 2, k, l) float32, which broadcast over
+    the mixes here), and all of it packed for the request's one fetch
+    (`pack_grid_rows`, energy the extra column). Parked columns hold no
+    tasks, so their zeroed rates and powers add nothing."""
+    C, _, k, l = rates.shape
+    counts, drifted = round_placements_jax(raw, mixes)
+    real = counts[:, :k, :l]
+    energy = expected_energy_batch_jax(real.reshape((C, -1, k, l)),
+                                       rates[:, None, 0], rates[:, None, 1])
+    return pack_grid_rows(real, drifted, xs, conv, moves, energy.reshape(-1))
 
 
 def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
@@ -104,7 +126,11 @@ def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
                          dvfs: DVFSModel):
     """Price every candidate frequency vector against every mix in ONE
     batched device solve (the decision-epoch hot path), then its energy in
-    one compiled call.
+    one compiled call, with one device round trip: the guarded candidates,
+    mixes and scaled rates go in once, the solve, the rounding and the
+    pricing are enqueued back to back, and one fetch brings every result.
+    Only placements whose rounded rows miss their mix are re-rounded and
+    re-priced (float64) on the host.
 
     Returns dict with `targets` (C, M, k, l) real-slice placements,
     `x` (C, M) guard-corrected X_sys, `energy` (C, M) J/task at the solved
@@ -117,23 +143,25 @@ def price_frequency_grid(nominal_mu: np.ndarray, P_nominal: np.ndarray,
         k, l = nominal_mu.shape
         mus = guarded_candidate_mus(nominal_mu, freq_grid, dvfs)
         g_mixes = guarded_mixes(mixes, l)
-    targets, xs, conv = solve_targets_grid_jax(mus, g_mixes)
-    with _obs_span("repro.price.energy"):
-        n_parked = (freq_grid == 0).sum(axis=1)
-        x = xs - GUARD_W * (n_parked + GUARD_DUMMY)[:, None]
-        real = targets[:, :, :k, :l]
-        # Energy priced in one compiled call: the placements (exact in
-        # float32) and each candidate's scaled (mu, P), scaled in float64
-        # here, shipped as one float32 array. Parked columns hold no tasks,
-        # so their zeroed rates/powers contribute nothing.
-        mu_s = dvfs.scale_mu(nominal_mu[None], freq_grid[:, None, :])
+        # each candidate's DVFS-scaled rates (the real block of its guarded
+        # matrix) and powers, scaled in float64 and cast here, shipped once
+        mu_s = mus[:, :k, :l]
         P_s = dvfs.scale_power(np.asarray(P_nominal)[None],
                                freq_grid[:, None, :])
-        packed = np.concatenate([real, mu_s[:, None], P_s[:, None]],
-                                axis=1).astype(np.float32)
-        energy = np.asarray(_energy_grid_jax(packed)).astype(np.float64)
-    return {"targets": real, "x": np.maximum(x, 0.0), "energy": energy,
-            "conv": conv}
+        rates = jnp.asarray(np.stack([mu_s, P_s], axis=1).astype(np.float32))
+    run = start_targets_grid(mus, g_mixes)
+    with _obs_span("repro.price.energy"):
+        rows = _price_grid_jax(run.raw, run.xs, run.conv, run.moves,
+                               run.mixes, rates)
+    res = run.finish(rows, (k, l))
+    energy = res.extra[..., 0].astype(np.float64)
+    for b in res.repaired:      # re-rounded on the host: priced there too
+        c, m = divmod(int(b), len(mixes))
+        energy[c, m] = _energy_per_task(res.targets[c, m], mu_s[c], P_s[c])
+    n_parked = (freq_grid == 0).sum(axis=1)
+    x = res.x_sys - GUARD_W * (n_parked + GUARD_DUMMY)[:, None]
+    return {"targets": res.targets, "x": np.maximum(x, 0.0), "energy": energy,
+            "conv": res.converged}
 
 
 def price_config_host(nominal_mu: np.ndarray, P_nominal: np.ndarray,
@@ -151,13 +179,7 @@ def price_config_host(nominal_mu: np.ndarray, P_nominal: np.ndarray,
     mu = dvfs.scale_mu(nominal_mu, freqs)[:, live]
     P = dvfs.scale_power(np.asarray(P_nominal, np.float64), freqs)[:, live]
     res = grin_block_solve(mu, np.asarray(mix, dtype=np.int64))
-    # eq. 19 with the explicit DVFS-scaled power matrix
-    N = np.asarray(res.N, dtype=np.float64)
-    col = N.sum(axis=0)
-    W_cols = np.where(col > 0, (N * P).sum(axis=0) / np.maximum(col, 1e-300),
-                      0.0)
-    e = float(W_cols.sum() / res.x_sys) if res.x_sys > 0 else np.inf
-    return float(res.x_sys), e
+    return float(res.x_sys), _energy_per_task(res.N, mu, P)
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ import pytest
 from _prop import given, settings, st
 
 from repro.core import (PROPORTIONAL_POWER, DVFSModel, grin_block_solve,
-                        random_affinity_matrix, system_throughput)
+                        grin_init, random_affinity_matrix, system_throughput)
 from repro.core.affinity import PowerModel
 from repro.faults import FaultScenario, PoolEvent, compose_event_streams
 from repro.sched import SchedulerCore
@@ -32,9 +32,12 @@ def _energy_per_task(N, mu, P):
 
 @given(st.integers(0, 10_000))
 def test_x_sys_monotone_in_single_frequency_step(seed):
-    """A single-pool frequency increase never lowers X_sys: exactly at a
-    fixed placement (column scaling), and through the re-solved GrIn
-    optimum (host f64)."""
+    """A single-pool frequency increase never lowers X_sys at a fixed
+    placement (column scaling). The re-solve at the stepped rates is a
+    heuristic's local optimum, which need not be monotone in one pool's
+    frequency; what the solver does guarantee there is checked instead: it
+    conserves the mix, converges to a placement no single move improves,
+    and ends at least as high as Algorithm 1's start (Lemma 8)."""
     rng = np.random.default_rng(seed)
     k, l = rng.integers(2, 5, size=2)
     mu = random_affinity_matrix(rng, k, l)
@@ -45,14 +48,24 @@ def test_x_sys_monotone_in_single_frequency_step(seed):
     i = int(np.searchsorted(levels, f[j]))
     f_up = f.copy()
     f_up[j] = levels[i + 1]
+    mu_up = DVFS.scale_mu(mu, f_up)
     lo = grin_block_solve(DVFS.scale_mu(mu, f), nt)
-    hi = grin_block_solve(DVFS.scale_mu(mu, f_up), nt)
+    hi = grin_block_solve(mu_up, nt)
     # fixed placement: X is linear in each pool's frequency with
-    # nonnegative coefficient, so the step helps pointwise...
-    x_fixed = system_throughput(lo.N, DVFS.scale_mu(mu, f_up))
+    # nonnegative coefficient, so the step helps pointwise
+    x_fixed = system_throughput(lo.N, mu_up)
     assert x_fixed >= lo.x_sys - 1e-12
-    # ...and the re-solved optimum can only be at least that good
-    assert hi.x_sys >= lo.x_sys - 1e-9 * (1 + lo.x_sys)
+    # the re-solve: exact mix, a single-move local maximum, above its start
+    assert hi.converged
+    np.testing.assert_array_equal(hi.N.sum(axis=1), nt)
+    slack = 1e-9 * (1 + hi.x_sys)
+    for p, src, dst in np.ndindex(k, l, l):
+        if src != dst and hi.N[p, src] > 0:
+            moved = hi.N.copy()
+            moved[p, src] -= 1
+            moved[p, dst] += 1
+            assert system_throughput(moved, mu_up) <= hi.x_sys + slack
+    assert hi.x_sys >= system_throughput(grin_init(mu_up, nt), mu_up) - slack
 
 
 @given(st.integers(0, 10_000))
@@ -180,19 +193,192 @@ def test_compiled_energy_matches_eager_and_host_pricing():
 def test_energy_pricing_compiles_once_per_grid_shape():
     """After one warm call, fresh mixes of the same (C, M, k, l) reuse the
     pricing program: no compile lands in a served window."""
-    from repro.sched.autoscale import _energy_grid_jax
+    from repro.sched.autoscale import _price_grid_jax
     rng = np.random.default_rng(3)
     mu = rng.uniform(2.0, 30.0, size=(3, 4))
     P = PROPORTIONAL_POWER.power_matrix(mu)
     grid = _parked_grid([[], [1], [0, 3]], 4, levels=(0.75, 1.25))
-    _energy_grid_jax.clear_cache()
+    _price_grid_jax.clear_cache()
     price_frequency_grid(mu, P, grid, rng.integers(1, 20, size=(5, 3)), DVFS)
-    assert _energy_grid_jax._cache_size() == 1
+    assert _price_grid_jax._cache_size() == 1
     for _ in range(3):
         priced = price_frequency_grid(mu, P, grid,
                                       rng.integers(1, 20, size=(5, 3)), DVFS)
         assert np.isfinite(priced["energy"]).all()
-    assert _energy_grid_jax._cache_size() == 1
+    assert _price_grid_jax._cache_size() == 1
+
+
+def _priced_as_before(mu, P, grid, mixes):
+    """The pricing composed as it was before it became one round trip: the
+    (C*M) batch repeated on the host, the solve and its results fetched,
+    rounding with largest-remainder repair on the host in float64, and eager
+    eq. 19 on the per-candidate rates repeated over the mixes."""
+    from repro.core import grin_solve_batch_jax
+    from repro.core.energy import expected_energy_batch_jax
+    from repro.core.slsqp import round_largest_remainder
+    from repro.sched.autoscale import GUARD_DUMMY, GUARD_W, guarded_mixes
+    k, l = mu.shape
+    mus = guarded_candidate_mus(mu, grid, DVFS)
+    g_mixes = guarded_mixes(mixes, l)
+    C, M = len(mus), len(g_mixes)
+    mix_b = np.tile(g_mixes, (C, 1))
+    raw, xs, conv, _ = grin_solve_batch_jax(np.repeat(mus, M, axis=0), mix_b)
+    raw = np.asarray(raw, dtype=np.float64)
+    out = raw.round().astype(np.int64)
+    for b in np.flatnonzero((out.sum(axis=-1) != mix_b).any(axis=-1)):
+        out[b] = round_largest_remainder(raw[b], mix_b[b])
+    real = np.maximum(out, 0)[:, :k, :l]
+    mu_s = DVFS.scale_mu(mu[None], grid[:, None, :])
+    P_s = DVFS.scale_power(P[None], grid[:, None, :])
+    energy = np.asarray(expected_energy_batch_jax(
+        real, np.repeat(mu_s, M, axis=0), np.repeat(P_s, M, axis=0)),
+        dtype=np.float64)
+    x = (np.asarray(xs).reshape(C, M)
+         - GUARD_W * ((grid == 0).sum(axis=1) + GUARD_DUMMY)[:, None])
+    return {"targets": real.reshape(C, M, k, l), "x": np.maximum(x, 0.0),
+            "energy": energy.reshape(C, M),
+            "conv": np.asarray(conv).reshape(C, M)}
+
+
+def test_one_round_trip_pricing_matches_the_composition_it_replaced():
+    """Built, rounded and priced on the device with one fetch, the grid
+    gives what the host-side composition gave: identical placements, x and
+    energy within 1e-6, the same convergence; parked candidates at mixed
+    DVFS levels included."""
+    rng = np.random.default_rng(17)
+    mu = rng.uniform(2.0, 30.0, size=(3, 4))
+    mu[2] = [1.0, 1.2, 0.9, 1.1]
+    P = PROPORTIONAL_POWER.power_matrix(mu)
+    grid = _parked_grid([[], [2], [1, 3], [0, 2, 3], [], [1]], 4,
+                        levels=(0.5, 1.25, 0.75, 1.0))
+    mixes = np.concatenate([rng.multinomial(600, [0.5, 0.3, 0.2], size=6),
+                            [[12, 9, 7], [1, 0, 5]]])
+    new = price_frequency_grid(mu, P, grid, mixes, DVFS)
+    old = _priced_as_before(mu, P, grid, mixes)
+    np.testing.assert_array_equal(new["targets"], old["targets"])
+    np.testing.assert_allclose(new["x"], old["x"], rtol=1e-6)
+    np.testing.assert_allclose(new["energy"], old["energy"], rtol=1e-6)
+    np.testing.assert_array_equal(new["conv"], old["conv"])
+
+
+def test_device_rounding_flags_exactly_the_drifted_rows():
+    """Rounded on the device half to even as np.round does, rows whose sums
+    miss their mix are flagged (before the clamp at 0), and the host
+    fallback re-rounds those alone to exact row sums."""
+    from repro.sched.api import _repair_targets, round_placements_jax
+    mixes = np.array([[7, 5], [4, 4]])
+    raw = np.array([
+        [[3.5, 3.5], [2.5, 2.5]],      # half to even: sums (8, 4) miss
+        [[2.0, 2.0], [1.0, 3.0]],      # clean
+        [[4.0, 3.0], [2.0, 3.0]],      # clean
+        [[2.5, 1.5], [-0.4, 4.4]],     # (4, 4): clean, -0.4 clamps to 0
+        [[-0.6, 7.6], [2.0, 3.0]],     # (-1 + 8): sums 7 but clamps to 8
+        [[4.0, 0.0], [0.6, 3.6]],      # (4, 5): misses
+    ], dtype=np.float32)               # instance b holds mix b % 2
+    counts, drifted = round_placements_jax(raw, mixes.astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(drifted),
+                                  [True, False, False, False, False, True])
+    counts = np.asarray(counts)
+    np.testing.assert_array_equal(counts, np.maximum(np.round(raw), 0))
+    assert counts.dtype == np.int32
+    targets = counts.astype(np.int64)
+    repaired = _repair_targets(targets, np.asarray(drifted), raw, mixes)
+    np.testing.assert_array_equal(repaired, [0, 5])
+    np.testing.assert_array_equal(targets[0].sum(axis=1), mixes[0])
+    np.testing.assert_array_equal(targets[5].sum(axis=1), mixes[1])
+    np.testing.assert_array_equal(targets[1:5], counts[1:5])
+
+
+def _drift(instances):
+    """A solver that returns its placements with two columns of the first
+    row of each listed instance moved up by 0.6: they round one up each,
+    so the rounded row misses its mix; largest remainder restores it."""
+    import jax.numpy as jnp
+    import repro.sched.api as api
+    real = api.grin_solve_batch_jax
+
+    def drifting(mu, mixes, **kw):
+        N, x, conv, moves = real(mu, mixes, **kw)
+        bump = np.zeros(N.shape, np.float32)
+        bump[list(instances), 0, :2] = 0.6
+        return N + jnp.asarray(bump), x, conv, moves
+    return drifting
+
+
+def test_host_fallback_repairs_and_reprices_drifted_rows(monkeypatch):
+    """Forced drift: the flagged placements come back with exact row sums,
+    equal to the clean solve's, their energy priced on the host in float64,
+    and the rest untouched."""
+    import repro.sched.api as api
+    rng = np.random.default_rng(23)
+    mu = rng.uniform(2.0, 30.0, size=(3, 4))
+    P = PROPORTIONAL_POWER.power_matrix(mu)
+    grid = _parked_grid([[], [1], [0, 3]], 4, levels=(0.75, 1.25))
+    mixes = rng.multinomial(300, [0.5, 0.3, 0.2], size=4)
+    clean = price_frequency_grid(mu, P, grid, mixes, DVFS)
+    M = len(mixes)
+    flagged = [1, 2 * M + 3]          # (candidate, mix) (0, 1) and (2, 3)
+    monkeypatch.setattr(api, "grin_solve_batch_jax", _drift(flagged))
+    drifted = price_frequency_grid(mu, P, grid, mixes, DVFS)
+    np.testing.assert_array_equal(drifted["targets"], clean["targets"])
+    mu_s = DVFS.scale_mu(mu[None], grid[:, None, :])
+    P_s = DVFS.scale_power(P[None], grid[:, None, :])
+    for b in flagged:
+        c, m = divmod(b, M)
+        N = drifted["targets"][c, m]
+        X = system_throughput(N, mu_s[c])
+        col = N.sum(axis=0)
+        W = np.where(col > 0, (N * P_s[c]).sum(axis=0) / np.maximum(col, 1),
+                     0.0)
+        assert drifted["energy"][c, m] == pytest.approx(W.sum() / X,
+                                                        rel=1e-12)
+    np.testing.assert_allclose(drifted["energy"], clean["energy"], rtol=1e-5)
+    keep = np.ones(clean["energy"].shape, bool)
+    keep.flat[flagged] = False
+    np.testing.assert_array_equal(drifted["energy"][keep],
+                                  clean["energy"][keep])
+
+
+def _count_fetches(monkeypatch):
+    """Record the shape of each device-to-host read of an array's values:
+    `jax.device_get`, `float()` and the like read `_value`; `np.asarray`
+    reads the buffer protocol where the array lives on the CPU."""
+    from jax._src.array import ArrayImpl
+    fetches = []
+    value, buffer = ArrayImpl._value, ArrayImpl.__buffer__
+
+    def counted_value(self):
+        fetches.append(self.shape)
+        return value.fget(self)
+
+    def counted_buffer(self, flags):
+        fetches.append(self.shape)
+        return buffer(self, flags)
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted_value))
+    monkeypatch.setattr(ArrayImpl, "__buffer__", counted_buffer)
+    return fetches
+
+
+def test_price_request_fetches_from_the_device_once(monkeypatch):
+    """A request with no flagged row waits on the device once: one fetch of
+    one packed array; a request with flagged rows adds one, of their raw
+    placements."""
+    import repro.sched.api as api
+    rng = np.random.default_rng(31)
+    mu = rng.uniform(2.0, 30.0, size=(3, 4))
+    P = PROPORTIONAL_POWER.power_matrix(mu)
+    grid = _parked_grid([[], [2], [1, 3]], 4, levels=(0.5, 1.0))
+    mixes = rng.multinomial(40, [0.5, 0.3, 0.2], size=5)
+    price_frequency_grid(mu, P, grid, mixes, DVFS)         # compile outside
+    fetches = _count_fetches(monkeypatch)
+    priced = price_frequency_grid(mu, P, grid, mixes, DVFS)
+    assert fetches == [(len(grid) * len(mixes), 4 + 1 + 3 * 4)]
+    assert np.array_equal(priced["targets"].sum(axis=3),
+                          np.broadcast_to(mixes[None], (3, 5, 3)))
+    monkeypatch.setattr(api, "grin_solve_batch_jax", _drift([4]))
+    fetches.clear()
+    price_frequency_grid(mu, P, grid, mixes, DVFS)
+    assert len(fetches) == 2 and fetches[1] == (1, 3 + 4, 4 + 1)
 
 
 def test_guarded_candidate_mus_shapes_and_guards():
@@ -208,13 +394,13 @@ def test_guarded_candidate_mus_shapes_and_guards():
 # ---------------------------------------------- one batched call per epoch
 
 def test_one_batched_device_call_per_decision_epoch(monkeypatch):
-    """The acceptance trace: per governor decide(), exactly ONE
-    solve_targets_grid_jax call carrying the whole fixed-width candidate
-    grid, backed by exactly ONE grin_solve_batch_jax device solve."""
+    """The acceptance trace: per governor decide(), exactly ONE grid solve
+    (`start_targets_grid`) carrying the whole fixed-width candidate grid,
+    backed by exactly ONE grin_solve_batch_jax device solve."""
     import repro.sched.api as api
     import repro.sched.autoscale as asc
     grid_calls, dev_calls = [], []
-    real_grid, real_dev = asc.solve_targets_grid_jax, api.grin_solve_batch_jax
+    real_grid, real_dev = asc.start_targets_grid, api.grin_solve_batch_jax
 
     def count_grid(mus, mixes, *a, **k):
         grid_calls.append(np.asarray(mus).shape)
@@ -224,7 +410,7 @@ def test_one_batched_device_call_per_decision_epoch(monkeypatch):
         dev_calls.append(1)
         return real_dev(*a, **k)
 
-    monkeypatch.setattr(asc, "solve_targets_grid_jax", count_grid)
+    monkeypatch.setattr(asc, "start_targets_grid", count_grid)
     monkeypatch.setattr(api, "grin_solve_batch_jax", count_dev)
     rng = np.random.default_rng(5)
     mu = rng.uniform(3.0, 25.0, size=(2, 3))

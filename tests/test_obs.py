@@ -243,10 +243,11 @@ def test_span_untraced_with_profiler_off_records_nothing():
     assert get_profiler().spans == []
 
 
-# the re-solve path's host phases, in the order one request runs them
+# the re-solve path's host phases, in the order one request runs them: the
+# energy pricing is enqueued behind the solve, before the one fetch
 PRICE_SPANS = ["repro.price.guard", "repro.grid.batch", "repro.grin.prep",
-               "repro.grin.dispatch", "repro.grid.fetch", "repro.grid.repair",
-               "repro.price.energy"]
+               "repro.grin.dispatch", "repro.price.energy", "repro.grid.fetch",
+               "repro.grid.repair"]
 
 
 def test_price_grid_spans_land_in_profiler_trace(tmp_path):

@@ -17,7 +17,7 @@ from repro.kernels.grin_moves import (block_move_gains_pallas,
                                       kernel_mode)
 from repro.sched import (SchedulerCore, solve_targets_grid_jax,
                          solve_targets_jax)
-from repro.sched.api import _repair_targets
+from repro.sched.api import _repair_targets, round_placements_jax
 
 
 # ------------------------------------------------------------ block deltas
@@ -160,14 +160,21 @@ def test_convergence_flags_and_scaled_cap():
 
 def test_solve_targets_repairs_float_row_drift():
     """Satellite (ISSUE PR3): float32 accumulation + .round() can violate
-    row sums on large mixes; largest-remainder repair restores them."""
+    row sums on large mixes; the device rounding flags such rows and the
+    host's largest-remainder repair restores them."""
     mixes = np.array([[7, 5]])
-    drifted = np.array([[[3.4, 3.4], [2.5, 2.4]]])   # rounds to sums (6, 6)
-    fixed = _repair_targets(drifted, mixes)
-    np.testing.assert_array_equal(fixed.sum(axis=2), mixes)
+
+    def rounded(raw):
+        counts, drifted = round_placements_jax(raw, mixes.astype(np.float32))
+        targets = np.asarray(counts).astype(np.int64)
+        _repair_targets(targets, np.asarray(drifted), raw, mixes)
+        return targets
+
+    drifted = np.array([[[3.4, 3.4], [2.5, 2.4]]], np.float32)  # sums (6, 4)
+    np.testing.assert_array_equal(rounded(drifted).sum(axis=2), mixes)
     # already-consistent rows round through unchanged
-    clean = np.array([[[4.0, 3.0], [2.0, 3.0]]])
-    np.testing.assert_array_equal(_repair_targets(clean, mixes), clean)
+    clean = np.array([[[4.0, 3.0], [2.0, 3.0]]], np.float32)
+    np.testing.assert_array_equal(rounded(clean), clean)
     # end to end: huge mixes keep exact row sums on both solver paths
     rng = np.random.default_rng(1)
     mu = random_affinity_matrix(rng, 3, 4)

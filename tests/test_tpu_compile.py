@@ -87,6 +87,30 @@ def test_block_solver_compiles_for_v5e_with_the_kernel(one_chip, objective):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
+def test_governor_request_compiles_for_v5e(one_chip):
+    """A governor re-solve's two programs at the fleet's shapes: 19
+    guarded (10 x 7) candidates broadcast against 64 mixes at the head of
+    the solver, then the pricing step that rounds, prices and packs for
+    the one fetch."""
+    from repro.core.grin import _grin_block_core
+    from repro.sched.autoscale import _price_grid_jax
+    C, Mx, k, l = 19, 64, 4, 6
+    Kg, Lg = k + l, l + 1
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    solver = _grin_block_core.lower(
+        spec((C, Kg, Lg)), spec((Mx, Kg)), spec((C, Kg, Lg)), n_sizes=M,
+        max_moves=None, kernel="pallas-compiled", objective=OBJ_X,
+        grid=True).compile()
+    assert "tpu_custom_call" in solver.as_text()
+    B = C * Mx
+    price = _price_grid_jax.lower(
+        spec((B, Kg, Lg)), spec((B,)), spec((B,), jnp.bool_),
+        spec((B,), jnp.int32), spec((Mx, Kg)), spec((C, 2, k, l))).compile()
+    assert price.memory_analysis().output_size_in_bytes >= B * (5 + k * l) * 4
+
+
 @pytest.mark.parametrize("block", [1024, 128])
 def test_flash_attention_compiles_for_v5e_at_qwen_prefill(one_chip, block):
     bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
