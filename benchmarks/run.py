@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + roofline.
+"""Benchmark driver: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV per module.
 
@@ -21,7 +21,7 @@ def main() -> None:
     from benchmarks import (bench_dispatch, fig4_7_cab_policies,
                             fig8_theory_vs_sim, fig9_12_grin_policies,
                             fig13_grin_vs_slsqp, fig14_runtime,
-                            fig15_16_real_platform, grin_plus_gap, roofline)
+                            fig15_16_real_platform, grin_plus_gap)
 
     jobs = {
         "dispatch": lambda: bench_dispatch.run(smoke=args.fast),
@@ -42,7 +42,6 @@ def main() -> None:
         "fig15_16": lambda: fig15_16_real_platform.run(),
         "grin_plus": lambda: grin_plus_gap.run(
             n_runs=60 if args.fast else 200),
-        "roofline": roofline.run,
     }
     only = set(args.only.split(",")) if args.only else None
 

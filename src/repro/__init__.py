@@ -1,2 +1,3 @@
-"""Reproduction of Chen & Marculescu, arXiv:1712.03209, grown into a
-JAX serving/training stack (see ROADMAP.md)."""
+"""Reproduction of Chen & Marculescu, arXiv:1712.03209, grown into a JAX
+heterogeneous-fleet scheduler with a device target solver, what-if engines
+and a serving path (see ROADMAP.md)."""

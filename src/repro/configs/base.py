@@ -50,11 +50,8 @@ class ModelConfig:
     # --- numerics ---
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    remat: bool = True
     attn_chunk_q: int = 1024        # chunked-softmax block sizes (jnp path)
     attn_chunk_k: int = 1024
-    loss_chunk: int = 512           # CE computed per seq-chunk (0 = off);
-                                    # bounds fp32 logits memory at big vocabs
 
     @property
     def resolved_head_dim(self) -> int:
@@ -88,9 +85,6 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str                       # train | prefill | decode
-    # Gradient accumulation microbatches (train only); tuned per arch via
-    # launch.shapes.resolve_microbatches when left at 0.
-    microbatches: int = 0
 
 
 TRAIN_4K = ShapeConfig("train_4k", seq_len=4_096, global_batch=256, kind="train")

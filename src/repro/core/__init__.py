@@ -13,8 +13,7 @@ from repro.core.energy import (DVFSModel, edp, edp_batch_jax, expected_delay,
                                scenario_identities)
 from repro.core.exhaustive import exhaustive_count, exhaustive_solve
 from repro.core.grin import (GrInBlockResult, GrInResult, grin_block_solve,
-                             grin_init, grin_solve, grin_solve_batch_jax,
-                             grin_solve_jax)
+                             grin_init, grin_solve, grin_solve_batch_jax)
 from repro.core.grin_energy import GrInEnergyResult, grin_energy_solve
 from repro.core.priority import (GrInPriorityResult, cab_priority_solve,
                                  class_energy_per_task, class_of_flat,

@@ -28,11 +28,11 @@ Lemma 8 monotonicity (every accepted block strictly increases X_sys).
 Convergence is declared on the m=1 signal, so the block solver's fixed
 points are exactly the single-move local maxima.
 
-Three implementations: NumPy single-move (host scheduler), NumPy block-move
-(reference mirror of the device solver, with a per-move X_sys history), and
-pure-JAX (jit/vmap-able): `grin_solve_jax` (single-move steepest ascent) and
-`grin_solve_batch_jax` (block-move, batched over (mu, mix) instances — the
-production path for on-device target grids).
+Two host solvers and one device solver: NumPy single-move `grin_solve`
+(host scheduler), NumPy block-move `grin_block_solve` (the float64
+reference mirror of the device solver, with a per-move X_sys history), and
+`grin_solve_batch_jax` (block-move, batched over (mu, mix) instances on the
+device — the production path for target grids).
 """
 from __future__ import annotations
 
@@ -50,14 +50,11 @@ from repro.core.throughput import (column_throughputs, delta_x_add,
                                    system_throughput_jax)
 
 _TOL = 1e-12
-# float32 solvers: accept only gains clearly above accumulated rounding
-# noise (relative to X_sys), else noise-level "improvements" can 2-cycle
-# forever. ~64 ULP at float32. The block solver converges at a finer
-# threshold: as the production path it polishes through the gain band the
-# single-move baseline stops in (still ~16 ULP above observed noise; a
-# noise cycle would only burn iterations until the move cap and report
-# converged=False, never corrupt the placement).
-_TOL32 = 4e-6
+# The float32 device solver accepts only gains clearly above accumulated
+# rounding noise (relative to X_sys), else noise-level "improvements" can
+# 2-cycle forever: ~16 ULP above observed noise. A noise cycle would only
+# burn iterations until the move cap and report converged=False, never
+# corrupt the placement.
 _TOL32_BLOCK = 1e-6
 
 
@@ -235,23 +232,8 @@ def grin_block_solve(mu: np.ndarray, n_tasks: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Pure-JAX GrIn: steepest-ascent variant inside lax.while_loop. Used where the
-# solver must live inside a jitted pipeline (vectorized policy sweeps, elastic
-# re-solve on device). Semantics: repeatedly apply the single best improving
-# move across ALL rows until none exists. Reaches a local max of the same
-# objective; may take a different path than the sweep variant.
+# Algorithm 1 on the device: the block solver's starting placement.
 # ---------------------------------------------------------------------------
-
-def _deltas_jax(N: jnp.ndarray, mu: jnp.ndarray):
-    colsum = N.sum(axis=0)                                   # (l,)
-    X = jnp.where(colsum > 0, (mu * N).sum(0) / jnp.maximum(colsum, 1), 0.0)
-    dplus = (mu - X[None, :]) / (colsum[None, :] + 1.0)      # (k, l)
-    single = colsum[None, :] <= 1
-    dm_reg = (X[None, :] - mu) / jnp.maximum(colsum[None, :] - 1.0, 1.0)
-    dminus = jnp.where(single, -mu, dm_reg)
-    dminus = jnp.where(N > 0, dminus, -jnp.inf)              # infeasible removes
-    return dplus, dminus
-
 
 def _grin_init_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
     """Algorithm 1 init (vectorized): (k, l) float32 placement."""
@@ -273,56 +255,6 @@ def _grin_init_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
     seed = (eff & (rank_of_col < nt[:, None])).astype(jnp.float32)
     rem = nt - seed.sum(axis=1)
     return seed + jax.nn.one_hot(slowest, l) * rem[:, None]
-
-
-def grin_solve_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray,
-                   max_moves: int | None = None, return_info: bool = False):
-    """jit/vmap-able single-move GrIn; returns the (k, l) placement (float32).
-
-    `max_moves=None` (default) scales the move cap with the population
-    (4 * sum(n_tasks) + 64) — the PR 2 fixed cap of 4096 silently returned
-    unconverged placements for larger mixes; an explicit int is a HARD cap
-    for callers that need bounded work (same contract as
-    `grin_solve_batch_jax`). With `return_info=True` (a trace-time static
-    flag) returns (N, converged, moves) so callers can detect the cap being
-    hit either way.
-    """
-    mu = jnp.asarray(mu, dtype=jnp.float32)
-    k, l = mu.shape
-    N0 = _grin_init_jax(mu, n_tasks)
-    total = jnp.asarray(n_tasks, dtype=jnp.float32).sum()
-    cap = (jnp.int32(max_moves) if max_moves is not None
-           else 4 * total.astype(jnp.int32) + 64)
-
-    def body(state):
-        N, _, moves = state
-        dplus, dminus = _deltas_jax(N, mu)
-        # gain[p, s, d] = dminus[p, s] + dplus[p, d], s != d
-        gain = dminus[:, :, None] + dplus[:, None, :]
-        eye = jnp.eye(l, dtype=bool)[None, :, :]
-        gain = jnp.where(eye, -jnp.inf, gain)
-        flat = jnp.argmax(gain)
-        p, s, d = jnp.unravel_index(flat, (k, l, l))
-        g = gain[p, s, d]
-        do = g > _TOL32 * (1.0 + system_throughput_jax(N, mu))
-        upd = (jax.nn.one_hot(p, k)[:, None]
-               * (jax.nn.one_hot(d, l) - jax.nn.one_hot(s, l))[None, :])
-        N = jnp.where(do, N + upd, N)
-        return N, do, moves + do.astype(jnp.int32)
-
-    def cond(state):
-        _, improved, moves = state
-        return improved & (moves < cap)
-
-    N, improved, moves = jax.lax.while_loop(
-        cond, body, (N0, jnp.array(True), jnp.array(0, jnp.int32)))
-    if return_info:
-        return N, ~improved, moves
-    return N
-
-
-def grin_x_sys_jax(mu: jnp.ndarray, n_tasks: jnp.ndarray) -> jnp.ndarray:
-    return system_throughput_jax(grin_solve_jax(mu, n_tasks), mu)
 
 
 # ---------------------------------------------------------------------------

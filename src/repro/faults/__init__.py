@@ -33,8 +33,8 @@ leaves every existing engine golden bit-identical — see
 tests/test_faults.py and tests/test_hazard.py.
 """
 from repro.faults.scenario import (FaultRealization, FaultScenario, PoolEvent,
-                                   compose_event_streams, crash, degrade,
-                                   make_storm)
+                                   compose_event_streams, crash,
+                                   decisions_to_events, degrade, make_storm)
 from repro.faults.targets import segment_targets
 from repro.faults.device import FaultBatch, build_fault_batch
 from repro.faults.host import run_closed_faults, run_open_faults

@@ -9,12 +9,12 @@ A scenario is a declarative bundle of resilience knobs:
 * ``fail_prob`` / ``fail_cap`` — transient task failures: each completion
   attempt fails independently with ``fail_prob`` (at most ``fail_cap``
   times per task) and the task re-executes from its last checkpoint.
-* ``ckpt_period`` / ``restart_overhead`` — checkpoint-restart cost model
-  (mirrors ``repro.train.checkpoint``): on a crash or transient failure a
-  task resumes from ``floor(done / period) * period`` seconds of preserved
-  work plus a fixed restart overhead; ``period=None`` means full
-  re-execution. The work between the last checkpoint and the fault is the
-  *lost work* charged to ``SimMetrics.wasted_work``.
+* ``ckpt_period`` / ``restart_overhead`` — checkpoint-restart cost model:
+  on a crash or transient failure a task resumes from
+  ``floor(done / period) * period`` seconds of preserved work plus a fixed
+  restart overhead; ``period=None`` means full re-execution. The work
+  between the last checkpoint and the fault is the *lost work* charged to
+  ``SimMetrics.wasted_work``.
 * ``hedge_classes`` — open/traffic mode only: arrivals of these classes
   are dispatched twice (primary + backup on a different pool);
   first-completion-wins, the partner is cancelled and its finished work
@@ -352,3 +352,21 @@ def compose_event_streams(primary: tuple, secondary: tuple, l: int) -> tuple:
                 cur = prod
     out.sort(key=lambda e: (e.time, e.pool))
     return tuple(out)
+
+
+def decisions_to_events(decisions, l: int) -> tuple:
+    """Convert a governor decision trace (`repro.sched.autoscale`: each
+    decision's ``.time`` and ``.freqs``) into `PoolEvent`s: scale =
+    frequency (mu ∝ f), 0 parks the pool. Only CHANGES emit events (the
+    realization validator rejects redundant ones) and t=0 decisions are the
+    initial state, not events."""
+    events = []
+    prev = np.ones(l)
+    for d in decisions:
+        f = np.asarray(d.freqs, dtype=np.float64)
+        for j in range(l):
+            if f[j] != prev[j] and d.time > 0:
+                events.append(PoolEvent(time=float(d.time), pool=j,
+                                        scale=float(f[j])))
+        prev = f.copy()
+    return tuple(events)

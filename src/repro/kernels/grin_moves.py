@@ -65,7 +65,7 @@ _NEG = -jnp.inf
 #                 _XE_TIE band of zero: the X-plateau energy polish that
 #                 follows an OBJ_XE solve (grin-e phase 2).
 OBJ_X, OBJ_XE, OBJ_E, OBJ_EDP, OBJ_E_GUARD = 0, 1, 2, 3, 4
-_XE_TIE = 4e-6          # float32 near-tie band, matches grin._TOL32
+_XE_TIE = 4e-6          # float32 near-tie band, relative to X_sys
 _LANES = 128            # batch tile: one vreg row of lanes
 
 

@@ -1,1 +1,2 @@
-"""Entry points: train / serve / dryrun launchers."""
+"""Entry points: the serving launcher (`python -m repro.launch.serve`) and
+the persistent compilation cache it and the chip benchmark switch on."""

@@ -4,14 +4,13 @@
 
     init(key) -> params
     forward(params, batch) -> logits (fp32)
-    loss(params, batch) -> (scalar, metrics)
     init_cache(batch_size, cache_len) -> cache pytree
     prefill(params, batch) -> (last_logits, cache)
     decode_step(params, tokens, cache, pos) -> (logits, cache)
     input_specs(shape) -> batch of ShapeDtypeStructs (dry-run stand-ins)
 
 Layer stacks are lax.scan-ed over stacked params ("stack_*" subtrees) so the
-HLO stays compact for 512-device compiles; remat applies per scanned block.
+HLO stays compact for 512-device compiles.
 """
 from __future__ import annotations
 
@@ -199,11 +198,6 @@ class Model:
     def _run_stack(self, params, x, *, positions, mode, caches, want_cache):
         """Returns (x, new_caches, aux_sum)."""
         cfg = self.cfg
-        remat = cfg.remat and mode == "full" and not want_cache
-
-        def maybe_remat(fn):
-            return jax.checkpoint(fn) if remat else fn
-
         aux_total = jnp.asarray(0.0, jnp.float32)
 
         if cfg.family in ("dense", "moe", "audio", "vlm"):
@@ -216,7 +210,7 @@ class Model:
                 return (h, aux + a), nc
 
             (x, aux_total), new = jax.lax.scan(
-                maybe_remat(body), (x, aux_total),
+                body, (x, aux_total),
                 (params["stack"], caches["stack"] if caches else None))
             return x, ({"stack": new} if (want_cache or mode == "decode") else None), aux_total
 
@@ -243,7 +237,7 @@ class Model:
 
             gc = caches["groups"] if caches else None
             (x, aux_total), new_g = jax.lax.scan(
-                maybe_remat(group), (x, aux_total),
+                group, (x, aux_total),
                 ({"mamba_stack": params["stack_groups"]}, gc))
             new_t = None
             if "stack_tail" in params:
@@ -254,7 +248,7 @@ class Model:
                                                want_cache=want_cache)
                     return (h, aux), nc
                 (x, aux_total), new_t = jax.lax.scan(
-                    maybe_remat(tail), (x, aux_total),
+                    tail, (x, aux_total),
                     (params["stack_tail"], caches["tail"] if caches else None))
             out_c = None
             if want_cache or mode == "decode":
@@ -283,7 +277,7 @@ class Model:
 
             gc = caches["groups"] if caches else None
             (x, aux_total), new_g = jax.lax.scan(
-                maybe_remat(group), (x, aux_total),
+                group, (x, aux_total),
                 (params["stack_groups"], gc))
             out_c = {"groups": new_g} if (want_cache or mode == "decode") else None
             return x, out_c, aux_total
@@ -303,67 +297,6 @@ class Model:
         if want_cache:
             return logits, caches, aux
         return logits, aux
-
-    def loss(self, params, batch):
-        cfg = self.cfg
-        if cfg.loss_chunk and cfg.family not in ("audio",):
-            return self._loss_chunked(params, batch)
-        logits, aux = self.forward(params, batch)
-        targets = batch["targets"]
-        if cfg.family == "audio":
-            # logits (B,S,K,V), targets (B,K,S)
-            tt = targets.transpose(0, 2, 1)                      # (B,S,K)
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(lp, tt[..., None], axis=-1)[..., 0]
-            mask = jnp.ones(tt.shape, jnp.float32)
-        else:
-            if cfg.family == "vlm":
-                npad = logits.shape[1] - targets.shape[1]
-                logits = logits[:, npad:]
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(lp, targets[..., None], axis=-1)[..., 0]
-            mask = batch.get("loss_mask",
-                             jnp.ones(targets.shape, jnp.float32))
-        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-        total = loss + aux
-        return total, {"ce": loss, "aux": aux}
-
-    def _loss_chunked(self, params, batch):
-        """CE via a scan over sequence chunks: fp32 logits are materialized
-        only (B, chunk, V) at a time — at 152k vocab this is the difference
-        between 2.5 GB and 300 MB of logits per device (§Perf iter 7)."""
-        cfg = self.cfg
-        x = self._embed(params, batch)
-        positions = jnp.arange(x.shape[1])
-        x, _, aux = self._run_stack(params, x, positions=positions,
-                                    mode="full", caches=None, want_cache=False)
-        targets = batch["targets"]
-        if cfg.family == "vlm":
-            x = x[:, x.shape[1] - targets.shape[1]:]
-        mask = batch.get("loss_mask", jnp.ones(targets.shape, jnp.float32))
-        b, s, d = x.shape
-        c = min(cfg.loss_chunk, s)
-        pad = (-s) % c
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-            targets = jnp.pad(targets, ((0, 0), (0, pad)))
-            mask = jnp.pad(mask, ((0, 0), (0, pad)))
-        n = x.shape[1] // c
-        xs = (x.reshape(b, n, c, d).swapaxes(0, 1),
-              targets.reshape(b, n, c).swapaxes(0, 1),
-              mask.reshape(b, n, c).swapaxes(0, 1))
-
-        def chunk(carry, inp):
-            nll_sum, m_sum = carry
-            xc, tc, mc = inp
-            logits = self._head(params, xc)
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(lp, tc[..., None], axis=-1)[..., 0]
-            return (nll_sum + (nll * mc).sum(), m_sum + mc.sum()), None
-
-        (nll_sum, m_sum), _ = jax.lax.scan(chunk, (0.0, 0.0), xs)
-        loss = nll_sum / jnp.maximum(m_sum, 1.0)
-        return loss + aux, {"ce": loss, "aux": aux}
 
     # ---------------- caches / serving ----------------
     def init_cache(self, batch: int, cache_len: int):

@@ -147,44 +147,3 @@ def param_pspec_tree(params_shape_tree, mesh: Mesh | None = None):
         s = logical_to_spec(param_logical_axes(keys, len(leaf.shape)))
         return even_spec(s, leaf.shape, mesh) if mesh is not None else s
     return jax.tree_util.tree_map_with_path(spec, params_shape_tree)
-
-
-def compute_param_specs(params_tree, mesh: Mesh | None = None):
-    """TP-only specs for the bf16 COMPUTE copy of the weights (ZeRO-2): the
-    fsdp axis is dropped so XLA gathers each weight ONCE per step (outside
-    the microbatch scan) instead of per-layer-per-microbatch."""
-    mesh = mesh or _CTX["mesh"]
-
-    def spec(path, leaf):
-        keys = tuple(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
-        axes = param_logical_axes(keys, len(leaf.shape))
-        axes = tuple(None if a == "fsdp" else a for a in axes)
-        s = logical_to_spec(axes)
-        return even_spec(s, leaf.shape, mesh) if mesh is not None else s
-    return jax.tree_util.tree_map_with_path(spec, params_tree)
-
-
-def cast_and_reshard_compute_params(params, dtype=None):
-    """bf16 cast + TP-only resharding constraint (no-op without a mesh)."""
-    import jax.numpy as jnp
-    dtype = dtype or jnp.bfloat16
-    mesh = _CTX["mesh"]
-
-    def cast(x):
-        return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
-
-    casted = jax.tree.map(cast, params)
-    if mesh is None:
-        return casted
-    specs = compute_param_specs(casted, mesh)
-    return jax.tree.map(
-        lambda x, s: jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, s)), casted, specs)
-
-
-def named_sharding_tree(mesh: Mesh, params_shape_tree):
-    specs = None
-    with use_mesh(mesh):
-        specs = param_pspec_tree(params_shape_tree)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                        is_leaf=lambda s: isinstance(s, P))

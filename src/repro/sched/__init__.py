@@ -1,15 +1,15 @@
 """Paper <-> framework bridge: heterogeneous pools + the unified
 Policy/SchedulerCore scheduling API."""
-from repro.sched.api import (Policy, SchedulerCore, SystemView, as_core,
-                             available_policies, get_policy, register_policy,
+from repro.sched.api import (Policy, SchedulerCore, StragglerTracker,
+                             SystemView, as_core, available_policies,
+                             get_policy, register_policy,
                              solve_targets_grid_jax, solve_targets_jax)
 from repro.sched.autoscale import (AutoscaleGovernor, BudgetSpec, Decision,
                                    GovernorConfig, StaticScaler,
-                                   UtilizationScaler, decisions_to_events,
-                                   price_frequency_grid, run_autoscaled)
+                                   UtilizationScaler, price_frequency_grid,
+                                   run_autoscaled)
 from repro.sched.baselines import BaselineClusterScheduler
-from repro.sched.priority import (CABPriorityPolicy, GrInPriorityPolicy,
-                                  priority_sim_config)
+from repro.sched.priority import CABPriorityPolicy, GrInPriorityPolicy
 from repro.sched.cluster import (ChipSpec, HeterogeneousCluster, Pool,
                                  PoolSpec, TaskRecord)
 from repro.sched.rates import (StepCost, affinity_from_roofline,

@@ -33,9 +33,9 @@ strict-priority service order (`order="PRIO"`) supplies the preemption-free
 class ordering at the processors.
 
 `solve_targets_jax` batches target re-solves over many type-mixes on device
-(block-move GrIn; `solver="single"` keeps the one-move-per-step variant) and
-`solve_targets_grid_jax` solves whole (mu x mix) grids in one call — the
-substrate for `SchedulerCore.elastic_what_if` pool-loss/pool-add planning.
+(block-move GrIn) and `solve_targets_grid_jax` solves whole (mu x mix) grids
+in one call — the substrate for `SchedulerCore.elastic_what_if`
+pool-loss/pool-add planning.
 `SchedulerCore.route_many` routes a whole burst of arrivals through one
 jit-compiled largest-deficit kernel for fleet-scale dispatch rates.
 """
@@ -57,14 +57,11 @@ from repro.core.affinity import PROPORTIONAL_POWER, PowerModel
 from repro.core.cab import cab_target_state
 from repro.core.energy import expected_energy_batch_jax
 from repro.core.exhaustive import exhaustive_solve
-from repro.core.grin import (_ladder, grin_solve, grin_solve_batch_jax,
-                              grin_solve_jax)
+from repro.core.grin import _ladder, grin_solve, grin_solve_batch_jax
 from repro.core.grin_energy import grin_energy_solve
 from repro.core.grin_plus import grin_multistart_solve
 from repro.core.slsqp import round_largest_remainder, slsqp_solve
-from repro.core.throughput import (state_from_pair,
-                                   system_throughput_jax, throughput_map_2x2)
-from repro.train.fault_tolerance import StragglerTracker
+from repro.core.throughput import state_from_pair, throughput_map_2x2
 
 
 @dataclasses.dataclass
@@ -387,19 +384,6 @@ class JoinShortestQueuePolicy(Policy):
 _F32_EXACT = 2 ** 24     # float32 holds every integer below this exactly
 
 
-@jax.jit
-def _solve_targets_single_grid(mus: jnp.ndarray, mixes: jnp.ndarray):
-    """Single-move solves of every matrix of `mus` (G, k, l) against every
-    mix of `mixes` (M, k), matrix-major, in one device call."""
-    G, M = mus.shape[0], mixes.shape[0]
-    mus = jnp.repeat(mus, M, axis=0)
-    mixes = jnp.tile(mixes, (G, 1))
-    targets, conv, moves = jax.vmap(
-        lambda m, nt: grin_solve_jax(m, nt, return_info=True))(mus, mixes)
-    xs = jax.vmap(system_throughput_jax)(targets, mus)
-    return targets, xs, conv, moves
-
-
 def round_placements_jax(raw, mixes):
     """Round a grid's float placements on the device, as `np.round` does
     (half to even), and flag the instances whose rounded rows miss their mix.
@@ -510,8 +494,7 @@ class GridSolve:
                           rows[:, 4:head].reshape(G, M, -1), repaired)
 
 
-def start_targets_grid(mus, mixes, solver: str = "block",
-                       objective: str = "max-x",
+def start_targets_grid(mus, mixes, objective: str = "max-x",
                        power: PowerModel | None = None, P=None) -> GridSolve:
     """Enqueue a whole (mu x mix) target grid's solve on the device and
     return without waiting; arguments as `solve_targets_grid_jax`. The
@@ -527,18 +510,11 @@ def start_targets_grid(mus, mixes, solver: str = "block",
         if mixes.sum(axis=1).max() >= _F32_EXACT:
             raise ValueError("a mix must hold fewer than 2**24 tasks, so "
                              "that float32 placements stay exact integers")
-        if solver not in ("block", "single"):
-            raise ValueError(f"unknown solver {solver!r}: block | single")
-        if solver == "single" and objective != "max-x":
-            raise ValueError("energy objectives need solver='block'")
         mus_d = jnp.asarray(np.asarray(mus, dtype=np.float32))
         mixes_d = jnp.asarray(mixes.astype(np.float32))
-    if solver == "block":
-        raw, xs, conv, moves = grin_solve_batch_jax(
-            mus_d, mixes_d, grid=True, objective=objective, power=power,
-            P=P, n_sizes=len(_ladder(int(mixes.sum(axis=1).max()))))
-    else:
-        raw, xs, conv, moves = _solve_targets_single_grid(mus_d, mixes_d)
+    raw, xs, conv, moves = grin_solve_batch_jax(
+        mus_d, mixes_d, grid=True, objective=objective, power=power,
+        P=P, n_sizes=len(_ladder(int(mixes.sum(axis=1).max()))))
     return GridSolve(raw, xs, conv, moves, mixes_d, mixes, mus_d.shape[0])
 
 
@@ -556,8 +532,7 @@ def physical_power_matrix(policy: Policy, mus: np.ndarray):
     return np.stack([power.power_matrix(m) for m in mus])
 
 
-def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
-                      objective: str = "max-x",
+def solve_targets_jax(mu, n_tasks_batch, objective: str = "max-x",
                       power: PowerModel | None = None, P=None):
     """Batched GrIn re-solve over many type mixes, vectorized on device.
 
@@ -567,13 +542,11 @@ def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
     dominate. One matrix against B mixes is a grid of one row, solved,
     rounded and fetched as `solve_targets_grid_jax` does.
 
-    `solver="block"` (default) is the block-move GrIn — O(log N)-ish device
-    steps per solve; `solver="single"` keeps the one-move-per-step variant
-    (the PR 2 path, retained as the benchmark baseline). Both reach local
-    maxima of the same objective and may land in a different (same-quality-
-    class) basin than the host sweep solver. `objective`/`power` switch the
-    block solver to the energy objectives (GrIn-E/GrIn-EDP); the single-move
-    solver is throughput-only. `P` overrides the (k, l) power matrix the
+    The solver is the block-move GrIn — O(log N)-ish device steps per
+    solve. It reaches a local maximum of the same objective and may land in
+    a different (same-quality-class) basin than the host sweep solver.
+    `objective`/`power` switch it to the energy objectives
+    (GrIn-E/GrIn-EDP). `P` overrides the (k, l) power matrix the
     energy objectives price moves against — callers solving under a
     class-weighted `device_mu` pass the PHYSICAL matrix here (see
     `physical_power_matrix`) so watts are never scaled by weights.
@@ -584,13 +557,12 @@ def solve_targets_jax(mu, n_tasks_batch, solver: str = "block",
         raise ValueError(f"n_tasks_batch must be (B, k={mu.shape[0]}); got "
                          f"{tuple(mixes.shape)}")
     with _obs_span("repro.targets.solve"):
-        targets, xs, _ = solve_targets_grid_jax(mu[None], mixes, solver,
-                                                objective, power, P)
+        targets, xs, _ = solve_targets_grid_jax(
+            mu[None], mixes, objective=objective, power=power, P=P)
     return targets[0], xs[0]
 
 
-def solve_targets_grid_jax(mus, mixes, solver: str = "block",
-                           objective: str = "max-x",
+def solve_targets_grid_jax(mus, mixes, objective: str = "max-x",
                            power: PowerModel | None = None, P=None):
     """Whole (mu x mix) target grid in one device call and one fetch.
 
@@ -603,12 +575,13 @@ def solve_targets_grid_jax(mus, mixes, solver: str = "block",
     which come back in one copy; only instances whose rounded rows miss
     their mix are re-rounded on the host (`_repair_targets`). This is what
     makes thousand-point elastic / energy what-if sweeps (mu batching)
-    cheap enough to run interactively. `objective`/`power` switch the block
+    cheap enough to run interactively. `objective`/`power` switch the
     solver to the energy objectives; `P` ((G, k, l) or (k, l)) overrides
     the priced power matrix — the physical one when `mus` are
     class-weighted (`physical_power_matrix`).
     """
-    run = start_targets_grid(mus, mixes, solver, objective, power, P)
+    run = start_targets_grid(mus, mixes, objective=objective, power=power,
+                             P=P)
     res = run.finish(_grid_rows_jax(run.raw, run.xs, run.conv, run.moves,
                                     run.mixes), run.raw.shape[1:])
     return res.targets, res.x_sys, res.converged
@@ -669,6 +642,34 @@ def _route_many_kernel(target, rank, counts0, types, valid):
 # ---------------------------------------------------------------------------
 
 _CACHE_CAP = 1024
+
+
+class StragglerTracker:
+    """EWMA of per-pool speed RELATIVE to expectation (1.0 = nominal).
+
+    Observations must be normalized per task class (expected/actual service
+    time) — raw rates would conflate a pool's task mix with its health."""
+
+    def __init__(self, n_pools: int, alpha: float = 0.3):
+        self.alpha = alpha
+        self.rates = np.ones(n_pools)     # relative speed, 1.0 = nominal
+        self.seen = np.zeros(n_pools, dtype=bool)
+
+    def observe(self, pool: int, rel_speed: float):
+        """rel_speed = expected_service_s / actual_service_s."""
+        if not self.seen[pool]:
+            self.rates[pool] = rel_speed
+            self.seen[pool] = True
+        else:
+            self.rates[pool] = (self.alpha * rel_speed
+                                + (1 - self.alpha) * self.rates[pool])
+
+    def slowdown_factors(self) -> np.ndarray:
+        """Per-pool relative speed (<1 = straggler, >1 = faster than nominal).
+
+        Normalized so the fleet-best healthy pool anchors at its own scale —
+        the scheduler multiplies base mu columns by these factors."""
+        return np.where(self.seen, self.rates, 1.0)
 
 
 class SchedulerCore:

@@ -47,7 +47,6 @@ from repro.core.energy import DVFSModel, expected_energy_batch_jax
 from repro.core.grin import grin_block_solve
 from repro.core.slsqp import round_largest_remainder
 from repro.core.throughput import system_throughput
-from repro.faults.scenario import PoolEvent
 from repro.obs.profile import span as _obs_span
 from repro.sched.api import (SchedulerCore, pack_grid_rows,
                              round_placements_jax, start_targets_grid)
@@ -454,27 +453,6 @@ class AutoscaleGovernor:
             live_pools = live_pools + [pool]
         core.set_frequencies(np.array([f[p] for p in live_pools]))
         return live_pools
-
-
-# ---------------------------------------------------------------------------
-# Decision traces -> fault-fabric realizations (replay / composition)
-# ---------------------------------------------------------------------------
-
-def decisions_to_events(decisions, l: int) -> tuple:
-    """Convert a governor decision trace into `PoolEvent`s on the PR 7
-    fault fabric: scale = frequency (mu ∝ f), 0 parks the pool. Only
-    CHANGES emit events (the realization validator rejects redundant
-    ones) and t=0 decisions are the initial state, not events."""
-    events = []
-    prev = np.ones(l)
-    for d in decisions:
-        f = np.asarray(d.freqs, dtype=np.float64)
-        for j in range(l):
-            if f[j] != prev[j] and d.time > 0:
-                events.append(PoolEvent(time=float(d.time), pool=j,
-                                        scale=float(f[j])))
-        prev = f.copy()
-    return tuple(events)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Priority-class policies + workload plumbing (arXiv:1712.03246).
+"""Priority-class policies (arXiv:1712.03246).
 
 The registry entries `grin-p` and `cab-p` are target policies over the
 CLASS-MAJOR FLATTENED problem (see `repro.core.priority`): the affinity
@@ -8,11 +8,12 @@ type) deficit rows, so the shared routing machinery needs no new state.
 Weights fold into the matrix the SOLVER ranks moves under (`device_mu`),
 never into the physical rates routing and EWMA folding observe.
 
-`priority_sim_config` builds the matching flattened `SimConfig` (tiled mu,
-flattened per-class mixes, `class_of_type` map, optional per-class size
-distributions) for both simulation engines; `order="PRIO"` selects the
-strict-priority preemption-free service order (class 0 first; within a
-class, FCFS).
+`repro.sim.priority_sim_config` (closed network) and
+`repro.traffic.priority_open_config` (open network) build the matching
+flattened `SimConfig` (tiled mu, flattened per-class mixes, `class_of_type`
+map, optional per-class size distributions) for both simulation engines;
+`order="PRIO"` selects the strict-priority preemption-free service order
+(class 0 first; within a class, FCFS).
 """
 from __future__ import annotations
 
@@ -36,8 +37,8 @@ def _flat_k(mu: np.ndarray, n_classes: int) -> int:
     if rows % n_classes:
         raise ValueError(
             f"flattened affinity has {rows} rows, not a multiple of "
-            f"C={n_classes} classes (build it with priority_sim_config / "
-            "flat_mu)")
+            f"C={n_classes} classes (build it with repro.sim."
+            "priority_sim_config / flat_mu)")
     return rows // n_classes
 
 
@@ -87,81 +88,5 @@ class CABPriorityPolicy(_WeightedFlatPolicy):
             C * k, -1)
 
 
-def priority_sim_config(mu, class_mixes, weights=None, *,
-                        distribution=None, class_distributions=None,
-                        order: str = "PS", **kwargs):
-    """Build the flattened `SimConfig` for a multi-class workload.
-
-    mu: (k, l) physical affinities; class_mixes: (C, k) per-class type
-    counts. The returned config runs on BOTH engines: its mu is the (C*k, l)
-    physical tile, its program counts the flattened mixes, and
-    `class_of_type` maps each flat row back to its class so the engines
-    report per-class X / E / response time / occupancy. `weights` is
-    accepted for symmetry but lives on the POLICY (grin-p/cab-p), not the
-    simulator — the substrate is class-blind; pass it to `get_policy`.
-    `class_distributions` (len C) gives each class its own task-size
-    distribution; `order="PRIO"` selects strict-priority preemption-free
-    service (class 0 first).
-    """
-    from repro.sim.simulator import SimConfig     # simulator imports sched.api
-    del weights                                   # scheduling-side knob only
-    class_mixes = np.asarray(class_mixes, dtype=np.int64)
-    if class_mixes.ndim != 2:
-        raise ValueError(f"class_mixes must be (C, k); got {class_mixes.shape}")
-    C, k = class_mixes.shape
-    if class_distributions is not None:
-        class_distributions = tuple(class_distributions)
-        if len(class_distributions) != C:
-            raise ValueError(f"need {C} class_distributions; got "
-                             f"{len(class_distributions)}")
-        if distribution is None:
-            distribution = class_distributions[0]
-    if distribution is None:
-        raise ValueError("need `distribution` (or `class_distributions`)")
-    return SimConfig(mu=flat_mu(mu, C),
-                     n_programs_per_type=flatten_mixes(class_mixes),
-                     distribution=distribution, order=order,
-                     class_of_type=class_of_flat(C, k),
-                     class_distributions=class_distributions, **kwargs)
-
-
-def priority_open_config(mu, processes, class_type_probs=None, *,
-                         distribution=None, class_distributions=None,
-                         order: str = "PRIO", **kwargs):
-    """Build the flattened OPEN-network `SimConfig` for a multi-class
-    workload (`repro.traffic`): one arrival process per class, types drawn
-    within each class from `class_type_probs` ((C, k) rows, default
-    uniform), on the same class-major flattened substrate as
-    `priority_sim_config`. Remaining kwargs (n_arrivals, warmup_arrivals,
-    queue_capacity, admit_limits, deadlines, seed, power, ...) pass through
-    to `repro.traffic.open_sim_config`.
-    """
-    from repro.traffic.arrivals import TrafficSpec
-    from repro.traffic.config import open_sim_config
-    mu = np.asarray(mu, dtype=np.float64)
-    k = mu.shape[0]
-    C = len(processes)
-    probs = (np.full((C, k), 1.0 / k) if class_type_probs is None
-             else np.asarray(class_type_probs, dtype=np.float64))
-    if probs.shape != (C, k):
-        raise ValueError(f"class_type_probs must be (C={C}, k={k}); got "
-                         f"{probs.shape}")
-    # class c's mass sits on its own flat rows c*k .. c*k + k - 1
-    flat_probs = np.zeros((C, C * k))
-    for c in range(C):
-        flat_probs[c, c * k:(c + 1) * k] = probs[c]
-    if class_distributions is not None:
-        class_distributions = tuple(class_distributions)
-        if distribution is None:
-            distribution = class_distributions[0]
-    if distribution is None:
-        raise ValueError("need `distribution` (or `class_distributions`)")
-    spec = TrafficSpec(processes=tuple(processes), type_probs=flat_probs)
-    return open_sim_config(flat_mu(mu, C), spec, distribution=distribution,
-                           order=order, class_of_type=class_of_flat(C, k),
-                           class_distributions=class_distributions, **kwargs)
-
-
-__all__ = ["GrInPriorityPolicy", "CABPriorityPolicy", "priority_sim_config",
-           "priority_open_config", "priority_mu", "flat_mu", "class_of_flat",
-           "flatten_mixes", "unflatten_state"]
+__all__ = ["GrInPriorityPolicy", "CABPriorityPolicy", "priority_mu",
+           "flat_mu", "class_of_flat", "flatten_mixes", "unflatten_state"]

@@ -3,7 +3,8 @@ analysis and the paper's scheduler.
 
 The paper measures mu_ij by timing kernels on each processor (Sec. 7.2). On a
 TPU fleet we instead ESTIMATE mu_ij from the roofline terms of the compiled
-step on pool j's hardware (and refine online with the StragglerTracker EWMA).
+step on pool j's hardware (and refine online with the EWMA of
+`repro.sched.api.StragglerTracker`).
 CAB/GrIn only need orderings, so roofline-grade estimates are sufficient —
 exactly the robustness the paper claims for CAB (Sec. 3.3, advantage 2).
 """

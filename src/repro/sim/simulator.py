@@ -18,8 +18,9 @@ Priority classes: `SimConfig.class_of_type` maps each task-type row of mu
 to a class c in {0..C-1}; both engines then report per-class throughput,
 response time, energy and occupancy in `SimMetrics` (single-class configs
 get the C == 1 reductions). `class_distributions` gives each class its own
-task-size distribution. The priority subsystem (`repro.sched.priority`)
-builds these flattened configs from (C, k) per-class mixes.
+task-size distribution. `priority_sim_config` builds these flattened
+configs from (C, k) per-class mixes for the priority policies
+(`repro.sched.priority`).
 
 Energy: a size-s i-type task on processor j occupies the processor for
 s / mu[i, j] dedicated seconds in either order, so task energy is
@@ -54,6 +55,7 @@ from collections import deque
 import numpy as np
 
 from repro.core.affinity import PowerModel, PROPORTIONAL_POWER
+from repro.core.priority import class_of_flat, flat_mu, flatten_mixes
 from repro.sched.api import Policy, SchedulerCore, SystemView, as_core
 from repro.sim.distributions import TaskSizeDistribution
 
@@ -93,6 +95,43 @@ class SimConfig:
     # events. None — or a scenario whose events never fire — leaves every
     # fault-free trajectory bit-identical (dedicated RNG substreams).
     faults: "object | None" = None
+
+
+def priority_sim_config(mu, class_mixes, weights=None, *,
+                        distribution=None, class_distributions=None,
+                        order: str = "PS", **kwargs):
+    """Build the flattened `SimConfig` for a multi-class workload.
+
+    mu: (k, l) physical affinities; class_mixes: (C, k) per-class type
+    counts. The returned config runs on BOTH engines: its mu is the (C*k, l)
+    physical tile, its program counts the flattened mixes, and
+    `class_of_type` maps each flat row back to its class so the engines
+    report per-class X / E / response time / occupancy. `weights` is
+    accepted for symmetry but lives on the POLICY (grin-p/cab-p), not the
+    simulator — the substrate is class-blind; pass it to `get_policy`.
+    `class_distributions` (len C) gives each class its own task-size
+    distribution; `order="PRIO"` selects strict-priority preemption-free
+    service (class 0 first).
+    """
+    del weights                                   # scheduling-side knob only
+    class_mixes = np.asarray(class_mixes, dtype=np.int64)
+    if class_mixes.ndim != 2:
+        raise ValueError(f"class_mixes must be (C, k); got {class_mixes.shape}")
+    C, k = class_mixes.shape
+    if class_distributions is not None:
+        class_distributions = tuple(class_distributions)
+        if len(class_distributions) != C:
+            raise ValueError(f"need {C} class_distributions; got "
+                             f"{len(class_distributions)}")
+        if distribution is None:
+            distribution = class_distributions[0]
+    if distribution is None:
+        raise ValueError("need `distribution` (or `class_distributions`)")
+    return SimConfig(mu=flat_mu(mu, C),
+                     n_programs_per_type=flatten_mixes(class_mixes),
+                     distribution=distribution, order=order,
+                     class_of_type=class_of_flat(C, k),
+                     class_distributions=class_distributions, **kwargs)
 
 
 @dataclasses.dataclass

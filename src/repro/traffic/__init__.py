@@ -33,7 +33,8 @@ from repro.traffic.loadgen import flash_crowd_times, make_load_traces
 from repro.traffic.quantiles import LogHistogram, exact_quantiles
 from repro.traffic.admission import (AdmissionController, SLOClass,
                                      default_admit_limits)
-from repro.traffic.config import OpenTraffic, open_sim_config
+from repro.traffic.config import (OpenTraffic, open_sim_config,
+                                  priority_open_config)
 from repro.traffic.host import run_open
 from repro.traffic.engine import (simulate_open_batch,
                                   simulate_open_policy_jax)

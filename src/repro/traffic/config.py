@@ -17,6 +17,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.core.priority import class_of_flat, flat_mu
 from repro.traffic.arrivals import TrafficSpec
 from repro.traffic.quantiles import LogHistogram
 
@@ -107,7 +108,7 @@ def open_sim_config(mu, spec: TrafficSpec, *, n_arrivals: int,
     """Build an open-mode `SimConfig` that runs on BOTH engines.
 
     mu is the (k, l) affinity matrix (class-major flattened for multi-class
-    workloads, as in `priority_sim_config`); `class_of_type` maps its rows
+    workloads, as in `priority_open_config`); `class_of_type` maps its rows
     to the spec's classes (default: all class 0). `target_mix` overrides the
     reference mix target policies solve at (default: `derive_target_mix`).
     Remaining kwargs (distribution, order, power, seed, ...) pass through
@@ -145,4 +146,40 @@ def open_sim_config(mu, spec: TrafficSpec, *, n_arrivals: int,
                      traffic=tr, **kwargs)
 
 
-__all__ = ["OpenTraffic", "open_sim_config", "derive_target_mix"]
+def priority_open_config(mu, processes, class_type_probs=None, *,
+                         distribution=None, class_distributions=None,
+                         order: str = "PRIO", **kwargs):
+    """Build the flattened OPEN-network `SimConfig` for a multi-class
+    workload: one arrival process per class, types drawn within each class
+    from `class_type_probs` ((C, k) rows, default uniform), on the same
+    class-major flattened substrate as `repro.sim.priority_sim_config`.
+    Remaining kwargs (n_arrivals, warmup_arrivals, queue_capacity,
+    admit_limits, deadlines, seed, power, ...) pass through to
+    `open_sim_config`.
+    """
+    mu = np.asarray(mu, dtype=np.float64)
+    k = mu.shape[0]
+    C = len(processes)
+    probs = (np.full((C, k), 1.0 / k) if class_type_probs is None
+             else np.asarray(class_type_probs, dtype=np.float64))
+    if probs.shape != (C, k):
+        raise ValueError(f"class_type_probs must be (C={C}, k={k}); got "
+                         f"{probs.shape}")
+    # class c's mass sits on its own flat rows c*k .. c*k + k - 1
+    flat_probs = np.zeros((C, C * k))
+    for c in range(C):
+        flat_probs[c, c * k:(c + 1) * k] = probs[c]
+    if class_distributions is not None:
+        class_distributions = tuple(class_distributions)
+        if distribution is None:
+            distribution = class_distributions[0]
+    if distribution is None:
+        raise ValueError("need `distribution` (or `class_distributions`)")
+    spec = TrafficSpec(processes=tuple(processes), type_probs=flat_probs)
+    return open_sim_config(flat_mu(mu, C), spec, distribution=distribution,
+                           order=order, class_of_type=class_of_flat(C, k),
+                           class_distributions=class_distributions, **kwargs)
+
+
+__all__ = ["OpenTraffic", "open_sim_config", "priority_open_config",
+           "derive_target_mix"]

@@ -1,7 +1,7 @@
 import os
 
-# Tests must see the single real CPU device (the dry-run sets its own flags
-# in a separate process); keep XLA_FLAGS free of forced device counts here.
+# Tests must see the single real CPU device; keep XLA_FLAGS free of forced
+# device counts here.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # hypothesis is an optional extra (`pip install -e .[test]`): property tests

@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 
 from repro.core import cab_target_state, exhaustive_solve, grin_solve, system_throughput
-from repro.sched import (ClusterScheduler, Policy, SchedulerCore, SystemView,
-                         available_policies, get_policy, solve_targets_jax)
+from repro.sched import (ClusterScheduler, Policy, SchedulerCore,
+                         StragglerTracker, SystemView, available_policies,
+                         get_policy, solve_targets_jax)
 from repro.sched.virtual import VirtualTimeCluster
 from repro.sim import ClosedNetworkSimulator, SimConfig, make_distribution
 
@@ -184,6 +185,17 @@ def test_pool_lost_and_added_resolve_through_core():
         core.complete(int(t), int(j))
         core.route(int(t))
     assert core.counts[:, 2].sum() > 0
+
+
+def test_straggler_tracker_relative_speed():
+    t = StragglerTracker(3, alpha=0.5)
+    for _ in range(6):
+        t.observe(0, 1.0)
+        t.observe(1, 0.4)    # pool 1 at 40% of nominal
+    f = t.slowdown_factors()
+    assert f[0] == pytest.approx(1.0, abs=0.05)
+    assert f[1] == pytest.approx(0.4, abs=0.1)
+    assert f[2] == 1.0       # unseen -> nominal
 
 
 def test_straggler_ewma_triggers_target_refresh():

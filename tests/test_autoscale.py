@@ -8,12 +8,12 @@ from _prop import given, settings, st
 from repro.core import (PROPORTIONAL_POWER, DVFSModel, grin_block_solve,
                         grin_init, random_affinity_matrix, system_throughput)
 from repro.core.affinity import PowerModel
-from repro.faults import FaultScenario, PoolEvent, compose_event_streams
+from repro.faults import (FaultScenario, PoolEvent, compose_event_streams,
+                          decisions_to_events)
 from repro.sched import SchedulerCore
 from repro.sched.autoscale import (AutoscaleGovernor, BudgetSpec,
                                    GovernorConfig, StaticScaler,
-                                   UtilizationScaler, decisions_to_events,
-                                   guarded_candidate_mus,
+                                   UtilizationScaler, guarded_candidate_mus,
                                    price_frequency_grid, run_autoscaled)
 
 DVFS = DVFSModel(alpha=3.0, levels=(0.5, 0.75, 1.0, 1.25))

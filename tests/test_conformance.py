@@ -15,9 +15,9 @@ import pytest
 
 from repro.core.affinity import PowerModel
 from repro.sched import get_policy
-from repro.sched.priority import flat_mu, flatten_mixes, priority_sim_config
+from repro.sched.priority import flat_mu, flatten_mixes
 from repro.sim import (ClosedNetworkSimulator, SimConfig, make_distribution,
-                       sweep_jax)
+                       priority_sim_config, sweep_jax)
 
 POWER = PowerModel(alpha=0.5)
 MUS = np.stack([np.random.default_rng(11).uniform(1, 30, size=(3, 3)),
@@ -430,8 +430,9 @@ def test_open_hazard_conformance_with_quantile_hedging():
 # execute.
 # ---------------------------------------------------------------------------
 from repro.core import DVFSModel  # noqa: E402
+from repro.faults import decisions_to_events  # noqa: E402
 from repro.sched.autoscale import (AutoscaleGovernor,  # noqa: E402
-                                   GovernorConfig, decisions_to_events)
+                                   GovernorConfig)
 from repro.traffic import DiurnalArrivals  # noqa: E402
 
 

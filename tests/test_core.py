@@ -4,13 +4,11 @@ import numpy as np
 import pytest
 from _prop import given, st
 
-import jax.numpy as jnp
-
 from repro.core import (CONSTANT_POWER, PROPORTIONAL_POWER, AffinityCase,
                         cab_closed_form_x, cab_solve, classify_2x2,
                         delta_x_add, delta_x_remove, exhaustive_solve,
                         expected_energy_per_task, grin_init, grin_solve,
-                        grin_solve_jax, random_affinity_matrix,
+                        grin_solve_batch_jax, random_affinity_matrix,
                         system_throughput, throughput_map_2x2)
 from repro.core.energy import edp, expected_delay, scenario_identities
 
@@ -121,16 +119,18 @@ def test_grin_near_optimal_on_paper_scale():
 
 
 def test_grin_jax_matches_numpy_quality():
+    """The device block solver against host Algorithm 2."""
     rng = np.random.default_rng(3)
+    mus, nts = [], []
     for _ in range(10):
-        mu = random_affinity_matrix(rng, 4, 3)
-        nt = rng.integers(1, 10, size=4)
-        xj = system_throughput(
-            np.asarray(grin_solve_jax(jnp.array(mu), jnp.array(nt))), mu)
+        mus.append(random_affinity_matrix(rng, 4, 3))
+        nts.append(rng.integers(1, 10, size=4))
+    N, _, _, _ = grin_solve_batch_jax(np.stack(mus), np.stack(nts))
+    for mu, nt, Nj in zip(mus, nts, np.asarray(N)):
+        xj = system_throughput(Nj, mu)
         xn = grin_solve(mu, nt).x_sys
         assert xj >= 0.95 * xn
-        assert np.allclose(
-            np.asarray(grin_solve_jax(jnp.array(mu), jnp.array(nt))).sum(1), nt)
+        assert np.allclose(Nj.sum(1), nt)
 
 
 # ---------------------------------------------------------------- energy

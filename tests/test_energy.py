@@ -202,8 +202,6 @@ def test_batched_solver_matches_host_energy_solver_quality():
             N[None], mu, POWER_HALF.power_matrix(mu))[0])
         assert e_dev == pytest.approx(
             expected_energy_per_task(N, mu, POWER_HALF), rel=1e-5)
-    with pytest.raises(ValueError, match="solver='block'"):
-        solve_targets_jax(mu, mixes, solver="single", objective="min-e")
 
 
 # ------------------------------------------------------------------- policies

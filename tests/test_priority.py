@@ -24,9 +24,9 @@ from repro.core.priority import (cab_priority_solve, class_energy_per_task,
 from repro.kernels.grin_moves import (block_move_gains_pallas,
                                       block_move_scores)
 from repro.sched import SchedulerCore, get_policy
-from repro.sched.priority import flat_mu, flatten_mixes, priority_sim_config
+from repro.sched.priority import flat_mu, flatten_mixes
 from repro.sim import (ClosedNetworkSimulator, SimConfig, make_distribution,
-                       simulate_policy_jax)
+                       priority_sim_config, simulate_policy_jax)
 
 DIST = make_distribution("exponential")
 
